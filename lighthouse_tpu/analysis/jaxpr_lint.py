@@ -245,20 +245,17 @@ def _dtype_bounds(dtype) -> tuple[int, int] | None:
 def _eqn_provenance(eqn) -> tuple[str, int]:
     """(repo-relative-or-absolute path, line) of the user frame that emitted
     this eqn — the `source_info` thread from the original Python source."""
-    try:
-        from jax._src import source_info_util
+    from jax._src import source_info_util
 
-        frame = source_info_util.user_frame(eqn.source_info)
-        if frame is not None:
-            path = frame.file_name
-            try:
-                path = Path(path).resolve().relative_to(REPO_ROOT).as_posix()
-            except (ValueError, OSError):
-                pass
-            return path, int(frame.start_line)
-    except Exception:
+    frame = source_info_util.user_frame(eqn.source_info.traceback)
+    if frame is None:
+        return "", 0
+    path = frame.file_name
+    try:
+        path = Path(path).resolve().relative_to(REPO_ROOT).as_posix()
+    except (ValueError, OSError):
         pass
-    return "", 0
+    return path, int(frame.start_line)
 
 
 def _spec_path(spec) -> str:
@@ -626,7 +623,7 @@ def _transfer(eqn, ins, ctx) -> list:
     # control flow (before the taint guard: bodies are analyzed even when
     # some operand is tainted, so findings inside them still surface)
     if name in (
-        "pjit", "closed_call", "core_call", "xla_call", "remat", "checkpoint",
+        "jit", "closed_call", "core_call", "xla_call", "remat", "checkpoint",
         "custom_jvp_call", "custom_vjp_call", "custom_jvp_call_jaxpr",
     ):
         for key in ("jaxpr", "call_jaxpr", "fun_jaxpr"):

@@ -12,7 +12,7 @@ exists to feed (SURVEY.md §2.8 items 1 & 3).
 
 from __future__ import annotations
 
-from ..crypto.bls.batch_verifier import active_for, verify_sets
+from ..crypto.bls.batch_verifier import active_for, is_device_error, verify_sets
 from ..state_transition import signature_sets as sigsets
 from ..state_transition.helpers import (
     StateTransitionError,
@@ -195,7 +195,9 @@ class PipelinedGossipVerifier:
                     self._verdicts(staged, future),
                     self.apply_to_fork_choice,
                 )
-            except Exception:  # noqa: BLE001 — hostile-input boundary
+            except Exception as exc:  # noqa: BLE001 — hostile-input boundary
+                if is_device_error(exc):
+                    raise
                 from ..common.metrics import PROCESSOR_ITEMS_DROPPED
 
                 PROCESSOR_ITEMS_DROPPED.inc()

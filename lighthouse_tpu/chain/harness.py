@@ -59,9 +59,10 @@ class BeaconChainHarness:
 
     # -- attestations (test_utils.rs make_attestations) ------------------------
 
-    def attestations_for_slot(self, state, head_root: bytes, slot: int):
-        """One fully-aggregated attestation per committee of `slot`, signed by
-        every committee member, attesting to `head_root`."""
+    def committee_votes(self, state, head_root: bytes, slot: int):
+        """(committee, AttestationData, per-member signatures) for every
+        committee of `slot`, every member attesting to `head_root` — the
+        single-signer gossip votes before aggregation."""
         ctx = self.ctx
         preset, spec = ctx.preset, ctx.spec
         epoch = compute_epoch_at_slot(slot, preset)
@@ -71,7 +72,7 @@ class BeaconChainHarness:
         else:
             target_root = state.block_roots[start_slot % preset.slots_per_historical_root]
 
-        data_by_index = {}
+        votes = []
         n_committees = get_committee_count_per_slot(state, epoch, preset)
         for index in range(n_committees):
             committee = get_beacon_committee(state, slot, index, preset, spec)
@@ -86,14 +87,20 @@ class BeaconChainHarness:
             )
             domain = get_domain(state, spec.domain_beacon_attester, epoch, preset)
             root = compute_signing_root(data, domain)
-            sigs = [self._sk_for(v).sign(root) for v in committee]
-            att = ctx.types.Attestation(
+            votes.append((committee, data, [self._sk_for(v).sign(root) for v in committee]))
+        return votes
+
+    def attestations_for_slot(self, state, head_root: bytes, slot: int):
+        """One fully-aggregated attestation per committee of `slot`, signed by
+        every committee member, attesting to `head_root`."""
+        return [
+            self.ctx.types.Attestation(
                 aggregation_bits=[True] * len(committee),
                 data=data,
-                signature=ctx.bls.aggregate_signatures(sigs).to_bytes(),
+                signature=self.ctx.bls.aggregate_signatures(sigs).to_bytes(),
             )
-            data_by_index[index] = att
-        return list(data_by_index.values())
+            for committee, data, sigs in self.committee_votes(state, head_root, slot)
+        ]
 
     # -- sync committee (altair+) ----------------------------------------------
 

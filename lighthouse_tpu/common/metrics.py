@@ -322,6 +322,11 @@ BLS_COALESCER_INTERNAL_ERRORS_TOTAL = REGISTRY.counter(
     "batches/futures (a climbing rate means every verdict is quietly "
     "going False)",
 )
+BLS_DEVICE_ERRORS_TOTAL = REGISTRY.counter(
+    "lighthouse_tpu_bls_device_errors_total",
+    "Coalesced batches failed by the device itself (dispatch, compile or "
+    "execution error): their futures raise the error; never bisected",
+)
 
 # Labeled pipeline families (this file owns the cross-cutting ones; stage
 # histograms fed by tracing spans live in common/tracing.py, validator
@@ -339,7 +344,8 @@ PROCESSOR_HANDLE_SECONDS = REGISTRY.histogram_vec(
 BLS_JIT_BUILDS_TOTAL = REGISTRY.counter_vec(
     "lighthouse_tpu_bls_jit_builds_total",
     "Device programs built per kernel family (cache-miss proxy: each build "
-    "is a new (S, K) bucket XLA will compile on first dispatch)",
+    "is a new stage program of a verify bucket, or a new pk_validate "
+    "bucket, that XLA compiles or loads from the persistent cache)",
     ("kernel",),
 )
 BLS_BATCH_PADDED_SIZE = REGISTRY.histogram(

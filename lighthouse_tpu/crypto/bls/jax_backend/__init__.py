@@ -7,14 +7,25 @@ np->jnp conversions silently widen to int64, changing every width
 assumption the proofs rest on (and hitting XLA's slow emulated 64-bit
 path on TPU) — so an x64 interpreter is refused loudly at import instead
 of producing subtly different kernels.
+
+The backend is also the program's one home for JAX's persistent
+compilation cache: each (S, K) verify bucket is minutes of TPU compile,
+so a node that restarts must find them again. `JAX_COMPILATION_CACHE_DIR`
+wins when set; otherwise the cache lives at the fixed path
+`<checkout>/.jax_cache` (the path is part of the cache key, so it never
+depends on a temp name, pid or time).
 """
 
+import pathlib
+
 import jax
+
+CACHE_DIR = pathlib.Path(__file__).resolve().parents[4] / ".jax_cache"
 
 
 def assert_x64_disabled() -> None:
     """Fail fast if jax_enable_x64 is on (also re-checkable at runtime —
-    tests call this under jax.experimental.enable_x64)."""
+    tests call this under jax.enable_x64)."""
     if jax.config.jax_enable_x64:
         raise RuntimeError(
             "lighthouse_tpu's jax backend requires jax_enable_x64=False: "
@@ -27,3 +38,5 @@ def assert_x64_disabled() -> None:
 
 
 assert_x64_disabled()
+if not jax.config.jax_compilation_cache_dir:  # JAX_COMPILATION_CACHE_DIR unset
+    jax.config.update("jax_compilation_cache_dir", str(CACHE_DIR))

@@ -208,9 +208,16 @@ def clear_cofactor(p: Jac) -> Jac:
 def map_to_g2(u0, u1) -> Jac:
     """Device portion of hash_to_curve: SSWU + isogeny evaluated ONCE on the
     2-stacked (u0, u1) batch (the heavy sqrt/inv exponent scans compile a
-    single instantiation), then point addition and cofactor clearing."""
+    single instantiation), then point addition and cofactor clearing.
+
+    The barrier keeps SSWU's output a value of its own. Without it, the TPU
+    compiler fuses SSWU into the cofactor clearing and gets y wrong (x stays
+    right): with a barrier every output matched the CPU on the v5e. Each
+    sub-step also matched when compiled alone, and so did the chain up to
+    the point addition (my chip runs, PR 21). `chip_smoke.py` checks this
+    map against the oracle at every batch width it compiles."""
     us = jnp.stack([u0, u1])  # (2, ..., 2, 32)
-    q = iso3_map(*sswu(us))
+    q = iso3_map(*lax.optimization_barrier(sswu(us)))
     q0 = Jac(q.x[0], q.y[0], q.z[0])
     q1 = Jac(q.x[1], q.y[1], q.z[1])
     return clear_cofactor(jac_add(FP2, q0, q1))

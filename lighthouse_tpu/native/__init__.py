@@ -2,14 +2,17 @@
 
 The reference pulls native code in through vendored deps (SURVEY.md §2.7:
 blst asm, ring SHA-256, LevelDB, SQLite). Here the in-repo native piece is
-the batched merkleization hasher (tree_hash.c); it is compiled on first
-use with the system toolchain and cached next to the source. Import never
-fails: callers check `available()` and fall back to hashlib.
+the batched merkleization hasher (tree_hash.c); it is compiled from the
+committed source with the system toolchain whenever the binary is missing
+or older than the source, and cached next to it (the binary is never
+committed). Import never fails: callers check `available()` and fall back
+to hashlib.
 """
 
 from __future__ import annotations
 
 import ctypes
+import os
 import pathlib
 import subprocess
 
@@ -22,14 +25,19 @@ _tried = False
 
 
 def _build() -> bool:
+    # build under a private name, then rename: concurrent processes (test
+    # workers) never load a half-written binary
+    tmp = _SO.with_name(f"{_SO.name}.{os.getpid()}.tmp")
     try:
         subprocess.run(
-            ["cc", "-O3", "-shared", "-fPIC", "-o", str(_SO), str(_SRC)],
+            ["cc", "-O3", "-shared", "-fPIC", "-o", str(tmp), str(_SRC)],
             check=True,
             capture_output=True,
         )
+        os.replace(tmp, _SO)
         return True
     except (OSError, subprocess.CalledProcessError):
+        tmp.unlink(missing_ok=True)
         return False
 
 

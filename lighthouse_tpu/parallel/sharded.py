@@ -26,11 +26,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:  # moved out of experimental in newer jax
-    from jax.experimental.shard_map import shard_map
-except ImportError:  # pragma: no cover
-    from jax.shard_map import shard_map  # type: ignore
-
 SETS_AXIS = "sets"
 
 
@@ -51,11 +46,11 @@ def build_sharded_verify(mesh: Mesh):
     rep = P()
 
     @partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(spec,) * 8,
         out_specs=rep,
-        check_rep=False,
+        check_vma=False,
     )
     def kernel(pk_x, pk_y, pk_inf, sig_x, sig_y, sig_inf, u, r_bits):
         local, ok_local = verify_pipeline_local(

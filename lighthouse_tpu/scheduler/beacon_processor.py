@@ -173,16 +173,23 @@ class BeaconProcessor:
         """Hostile-input boundary for drain handlers: when a batch handler
         raises, retry per item and drop the single offender — one malformed
         message must not wedge the drain (the worker-panic isolation the
-        reference gets from per-task workers)."""
+        reference gets from per-task workers). A device error is no
+        offender: it propagates, so a dead accelerator stops the node
+        instead of dropping every message."""
+        from ..crypto.bls.batch_verifier import is_device_error
 
         def run(items):
             try:
                 handler(items)
-            except Exception:  # noqa: BLE001 — hostile-input boundary
+            except Exception as exc:  # noqa: BLE001 — hostile-input boundary
+                if is_device_error(exc):
+                    raise
                 for item in items:
                     try:
                         handler([item])
-                    except Exception:  # noqa: BLE001
+                    except Exception as exc:  # noqa: BLE001
+                        if is_device_error(exc):
+                            raise
                         from ..common.metrics import PROCESSOR_ITEMS_DROPPED
 
                         PROCESSOR_ITEMS_DROPPED.inc()

@@ -1,15 +1,11 @@
 """Run the BLS conformance matrix against the jax backend on the attached
 accelerator (tests/ force the CPU mesh; this script runs on the real chip)."""
 
-import os
 import pathlib
 import sys
 
 _ROOT = pathlib.Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(_ROOT))
-os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", str(_ROOT / ".jax_cache"))
-os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "1")
-os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES", "0")
 
 
 def main() -> None:

@@ -20,9 +20,6 @@ import time
 _ROOT = pathlib.Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(_ROOT))
 
-os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", str(_ROOT / ".jax_cache"))
-os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0")
-os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES", "0")
 
 N_SETS = int(os.environ.get("PROFILE_N_SETS", "128"))
 REPS = int(os.environ.get("PROFILE_REPS", "5"))
@@ -39,10 +36,6 @@ def med(fn, reps=REPS):
 
 def main() -> None:
     import jax
-    # the ambient plugin pins the persistent-cache threshold at startup;
-    # config.update outranks it (see tests/conftest.py)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
     import jax.numpy as jnp
 
     from lighthouse_tpu.crypto import bls

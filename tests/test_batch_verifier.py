@@ -142,6 +142,167 @@ def test_bisection_blames_exactly_the_invalid_sets():
         svc.stop()
 
 
+class _RaisingFuture:
+    def __init__(self, exc):
+        self._exc = exc
+
+    def result(self):
+        raise self._exc
+
+
+class DeviceFailingBackend(StubBackend):
+    """Async backend whose device fails: the JAX runtime error is raised at
+    dispatch (a compile error, an HBM OOM at allocation) or when the
+    verdict is fetched (an execution error)."""
+
+    def __init__(self, where: str):
+        super().__init__()
+        self.where = where
+
+    def verify_signature_sets_async(self, sets, rng=None):
+        import jax
+
+        with self._lock:
+            self.calls.append(len(sets))
+        exc = jax.errors.JaxRuntimeError("RESOURCE_EXHAUSTED: out of HBM")
+        if self.where == "dispatch":
+            raise exc
+        return _RaisingFuture(exc)
+
+
+@pytest.mark.parametrize("where", ["dispatch", "result"])
+def test_device_error_fails_futures_without_bisection(where):
+    """A JAX runtime error is no verdict: every future of the batch raises
+    it, the device-error counter counts it, and no bisection dispatch is
+    sent (the failed batch is the only call the backend sees)."""
+    import jax
+
+    from lighthouse_tpu.common.metrics import (
+        BLS_BISECTION_DISPATCHES_TOTAL,
+        BLS_DEVICE_ERRORS_TOTAL,
+    )
+
+    backend = DeviceFailingBackend(where)
+    svc = BatchVerifier(backend, s_bucket=4, max_wait=1.0)
+    e0 = BLS_DEVICE_ERRORS_TOTAL.value
+    d0 = BLS_BISECTION_DISPATCHES_TOTAL.value
+    # queue all four before the collector starts: one full S=4 batch
+    svc._running = True
+    futures = [svc.submit([StubSet(valid=i != 2)]) for i in range(4)]
+    svc._running = False
+    svc.start()
+    try:
+        for f in futures:
+            with pytest.raises(jax.errors.JaxRuntimeError, match="out of HBM"):
+                f.result(timeout=10.0)
+        assert backend.calls == [4]
+        assert BLS_DEVICE_ERRORS_TOTAL.value - e0 == 1
+        assert BLS_BISECTION_DISPATCHES_TOTAL.value == d0
+    finally:
+        svc.stop()
+
+
+def test_stopped_service_raises_device_error_to_the_caller():
+    import jax
+
+    class SyncDeviceFailure(StubBackend):
+        def verify_signature_sets(self, sets, rng=None):
+            self.calls.append(len(sets))
+            raise jax.errors.JaxRuntimeError("INTERNAL: device lost")
+
+    backend = SyncDeviceFailure()
+    fut = BatchVerifier(backend).submit([StubSet(), StubSet()])
+    with pytest.raises(jax.errors.JaxRuntimeError, match="device lost"):
+        fut.result(timeout=1.0)
+    assert backend.calls == [2]  # no per-set retries
+
+
+def test_decode_error_set_still_gets_a_false_verdict():
+    """Hostile input stays a verdict: a set whose staging raises the
+    backend's DecodeError is blamed alone (False) by bisection while its
+    batchmates verify True, and no device error is counted."""
+    from lighthouse_tpu.common.metrics import BLS_DEVICE_ERRORS_TOTAL
+    from lighthouse_tpu.crypto.bls.ref.api import DecodeError
+
+    @dataclass
+    class Undecodable:
+        valid: bool = False
+
+    class StagingBackend(StubBackend):
+        def verify_signature_sets_async(self, sets, rng=None, s_floor=None):
+            if any(isinstance(s, Undecodable) for s in sets):
+                raise DecodeError("signature is not a curve point")
+            return _Verdict(self.verify_signature_sets(sets))
+
+    class _Verdict:
+        def __init__(self, ok):
+            self._ok = ok
+
+        def result(self):
+            return self._ok
+
+    e0 = BLS_DEVICE_ERRORS_TOTAL.value
+    svc = BatchVerifier(StagingBackend(), max_wait=0.01).start()
+    try:
+        sets = [StubSet(), Undecodable(), StubSet(), StubSet()]
+        assert svc.submit(sets).result(timeout=10.0) == [True, False, True, True]
+    finally:
+        svc.stop()
+    assert BLS_DEVICE_ERRORS_TOTAL.value == e0
+
+
+def test_processor_isolation_reraises_device_errors():
+    """The drain's hostile-input boundary retries per item and drops
+    offenders — but a device error is no offender: it propagates at once,
+    with no per-item retries."""
+    import jax
+
+    from lighthouse_tpu.scheduler import BeaconProcessor
+
+    seen = []
+
+    def handler(items):
+        seen.append(len(items))
+        raise jax.errors.JaxRuntimeError("INTERNAL: device lost")
+
+    with pytest.raises(jax.errors.JaxRuntimeError):
+        BeaconProcessor.isolated(handler)([1, 2, 3])
+    assert seen == [3]
+
+
+def test_bisection_halves_keep_the_failed_batch_bucket():
+    """Blame dispatches are staged at the failed batch's size (s_floor),
+    so on the device they reuse its compiled bucket instead of compiling
+    every smaller one on the way down."""
+
+    class _Verdict:
+        def __init__(self, ok):
+            self._ok = ok
+
+        def result(self):
+            return self._ok
+
+    class FloorRecordingBackend(StubBackend):
+        def __init__(self):
+            super().__init__()
+            self.floors = []
+
+        def verify_signature_sets_async(self, sets, rng=None, s_floor=None):
+            self.floors.append((len(sets), s_floor))
+            return _Verdict(all(s.valid for s in sets))
+
+    backend = FloorRecordingBackend()
+    svc = BatchVerifier(backend, max_wait=0.01).start()
+    try:
+        sets = [StubSet(valid=i != 5) for i in range(8)]
+        assert svc.submit(sets).result(timeout=10.0) == [i != 5 for i in range(8)]
+    finally:
+        svc.stop()
+    assert backend.floors[0] == (8, None)
+    assert [n for n, _ in backend.floors[1:]] == [4, 4, 2, 2, 1, 1]
+    assert {f for _, f in backend.floors[1:]} == {8}
+
+
 def test_multi_set_submission_gets_per_set_verdicts():
     backend = StubBackend()
     svc = BatchVerifier(backend, max_wait=0.01).start()
@@ -213,6 +374,33 @@ def test_kick_flushes_a_partial_batch_before_its_deadline():
         while len(backend.calls) < 2 and time.monotonic() < deadline:
             time.sleep(0.001)
         assert len(backend.calls) == 2
+        backend.gate.set()
+        assert f2.result(timeout=10.0) == [True]
+    finally:
+        svc.stop()
+
+
+def test_kick_with_nothing_pending_does_not_flush_later_submissions():
+    """The end-of-drain kick is a flush of what is pending at that moment;
+    a kick that found nothing must not make the next set dispatch alone
+    while the device is busy."""
+    backend = GatedBackend()
+    svc = BatchVerifier(backend, s_bucket=128, max_wait=30.0).start()
+    try:
+        svc.submit([StubSet()])  # idle -> dispatched, gate holds it
+        deadline = time.monotonic() + 5.0
+        while len(backend.calls) < 1 and time.monotonic() < deadline:
+            time.sleep(0.001)
+        svc.kick()  # nothing pending
+        time.sleep(0.05)
+        f2 = svc.submit([StubSet()])  # device busy + 30 s deadline: parked
+        time.sleep(0.05)
+        assert backend.calls == [1]
+        svc.kick()
+        deadline = time.monotonic() + 5.0
+        while len(backend.calls) < 2 and time.monotonic() < deadline:
+            time.sleep(0.001)
+        assert backend.calls == [1, 1]
         backend.gate.set()
         assert f2.result(timeout=10.0) == [True]
     finally:

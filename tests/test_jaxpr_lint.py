@@ -164,7 +164,7 @@ def test_dtype_catches_int64_promotion_under_x64():
     def widen(x):
         return x.astype(jnp.int64) * 2
 
-    with jax.experimental.enable_x64():
+    with jax.enable_x64():
         findings = analyze_fixture(widen, (np.zeros(8, np.int32),), [LIMB12])
     wide = [f for f in findings if f.rule == "jaxpr-dtype"]
     assert wide and "int64" in wide[0].message, [f.format() for f in findings]
@@ -393,7 +393,7 @@ def test_x64_guard_accepts_default_and_rejects_x64():
     from lighthouse_tpu.crypto.bls import jax_backend
 
     jax_backend.assert_x64_disabled()  # tier-1 config: x64 off
-    with jax.experimental.enable_x64():
+    with jax.enable_x64():
         with pytest.raises(RuntimeError, match="x64"):
             jax_backend.assert_x64_disabled()
 

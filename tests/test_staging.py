@@ -301,6 +301,71 @@ def test_sync_committee_resolution_goes_through_cache():
     assert bytes(again.aggregate_pubkey) == bytes(committee.aggregate_pubkey)
 
 
+# -- verify-kernel builds ----------------------------------------------------------
+
+
+class _FakeLowered:
+    """Stands in for a lowered stage program: compile() blocks until the
+    test releases it (a TPU compile takes minutes) and counts itself."""
+
+    def __init__(self, gate, builds, fail=False):
+        self._gate, self._builds, self._fail = gate, builds, fail
+
+    def compile(self):
+        self._gate.wait(10.0)
+        self._builds.append(1)
+        if self._fail:
+            raise RuntimeError("compile refused")
+        return lambda flat: True
+
+
+def test_verify_stages_build_once_across_threads_and_buckets(monkeypatch):
+    """Callers that meet a stage while another thread compiles it wait for
+    that compile; buckets share the stages their shapes allow (the final
+    exponentiation always, the S-only stages per S); a failed build raises
+    in every waiter and is not cached."""
+    import threading
+    import time
+
+    gate, builds, lowered = threading.Event(), [], []
+
+    def lower(key):
+        lowered.append(key)
+        return _FakeLowered(gate, builds, fail=key == ("keys", 8, 2))
+
+    monkeypatch.setattr(japi, "_lower_stage", lower)
+    monkeypatch.setattr(japi, "_kernels", {})
+    monkeypatch.setattr(japi, "_chains", {})
+    got = []
+    waiter = threading.Thread(target=lambda: got.append(japi._verify_kernel(4, 1)))
+    waiter.start()
+    deadline = time.monotonic() + 10.0
+    while not lowered and time.monotonic() < deadline:
+        time.sleep(0.001)
+    again = threading.Thread(target=lambda: got.append(japi._verify_kernel(4, 1)))
+    again.start()
+    gate.set()
+    waiter.join(10.0)
+    again.join(10.0)
+    assert len(got) == 2 and got[1] is japi._verify_kernel(4, 1)
+    assert len(builds) == len(japi._VERIFY_CHAIN)
+
+    # (4, 64) shares the S-only and shape-free stages; (16, 4) final only
+    ready = japi.compile_verify_kernels([(4, 64), (16, 4)])
+    assert set(ready) == {(4, 64), (16, 4)}
+    assert len(builds) == len(japi._VERIFY_CHAIN) + 2 + 5
+    with pytest.raises(RuntimeError, match="compile refused"):
+        japi.compile_verify_kernels([(8, 2)])
+    assert ("keys", 8, 2) not in japi._kernels
+
+
+def test_staging_floor_rounds_to_a_pow2_bucket(sets):
+    """s_floor (bisection keeps its batch's bucket) never makes a non-pow2
+    S: 11 sets with a floor of 100 stage into the 128 bucket."""
+    staged = japi.stage_sets(sets, s_floor=100)
+    assert staged[2].shape == japi.batch_bucket(len(sets), 3, 100) == (128, 4)
+
+
 # -- the coalescer's staging stage ---------------------------------------------
 
 
