@@ -348,10 +348,35 @@ BLS_JIT_BUILDS_TOTAL = REGISTRY.counter_vec(
     "bucket, that XLA compiles or loads from the persistent cache)",
     ("kernel",),
 )
+# How each verify stage program built came to be: loaded from the persistent
+# compile cache, or compiled and written there. A node whose restart shows
+# misses spends minutes compiling before its first batch.
+BLS_COMPILE_CACHE_TOTAL = REGISTRY.counter_vec(
+    "lighthouse_tpu_bls_compile_cache_total",
+    "Verify stage programs built, by persistent-cache result: hit (loaded), "
+    "miss (compiled and written), uncached (compiled; cache off, or the "
+    "program under the cache's size or compile-time floor)",
+    ("result",),
+)
 BLS_BATCH_PADDED_SIZE = REGISTRY.histogram(
     "lighthouse_tpu_bls_batch_padded_size",
     "Padded set-count (S bucket) of each dispatched verify batch",
     buckets=(4, 8, 16, 32, 64, 128, 256, 512),
+)
+# Every staged batch, bisection halves included: of its S rows, those that
+# carry a set and the padding; of its S x K key slots, those that hold a
+# real key and the padding (infinity points and the padding rows' keys).
+BLS_STAGED_ROWS_TOTAL = REGISTRY.counter_vec(
+    "lighthouse_tpu_bls_staged_rows_total",
+    "Rows of staged verify batches: row=set carries a signature set, "
+    "row=pad pads the batch to its S bucket",
+    ("row",),
+)
+BLS_STAGED_KEY_SLOTS_TOTAL = REGISTRY.counter_vec(
+    "lighthouse_tpu_bls_staged_key_slots_total",
+    "S x K key slots of staged verify batches: slot=key holds a real public "
+    "key, slot=pad is padding",
+    ("slot",),
 )
 
 # Host staging fast path (stage_sets): per-point packed-limb caching and

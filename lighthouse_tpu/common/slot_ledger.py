@@ -12,11 +12,13 @@ slot's wall time to named stages:
     gossip_admission   admission checks + set building (gossip handlers)
     coalesce_wait      time submissions waited for batch formation
     staging            host packing / hash-to-field before dispatch
-    device_execute     device (or backend) execution of verify batches
+    device_execute     device (or backend) execution of verify batches,
+                       with their dispatch and bisection
     state_transition   block state transitions + bulk signature checks
     fork_choice        proto-array updates
     store_write        persisting blocks/states
-    other_traced       spans not mapped to a headline stage
+    other_traced       spans not mapped to a headline stage (among them
+                       the stage-program builds)
     unattributed       wall time no span covered (residual — makes the
                        attribution sum EXACTLY equal measured wall time)
 
@@ -66,8 +68,12 @@ SLOT_VALIDATORS_SUPPORTABLE = REGISTRY.gauge(
     "the last slot's verification stages, extrapolated to a full slot budget",
 )
 
-# span name -> ledger stage. Unmapped spans land in other_traced; the
-# residual (wall minus everything traced) lands in unattributed.
+# span name -> ledger stage. Unmapped spans land in other_traced (among
+# them the stage-program builds, bls_lower and bls_compile); the residual
+# (wall minus everything traced) lands in unattributed. bls_dispatch (pack,
+# host-to-device copy, launches) nests in bls_batch_verify and counts as
+# device_execute, as it did as that span's self time; bls_bisect (the
+# resolver's blame: its halves' dispatches and verdict waits) too.
 STAGE_OF_SPAN = {
     "processor_handle_gossip_attestation": "gossip_admission",
     "processor_handle_gossip_aggregate": "gossip_admission",
@@ -78,6 +84,8 @@ STAGE_OF_SPAN = {
     "bls_h2c_host": "staging",
     "bls_batch_verify": "device_execute",
     "bls_device_execute": "device_execute",
+    "bls_dispatch": "device_execute",
+    "bls_bisect": "device_execute",
     "state_transition": "state_transition",
     "signature_verify": "state_transition",
     "fork_choice": "fork_choice",

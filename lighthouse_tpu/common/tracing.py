@@ -1,23 +1,30 @@
-"""Pipeline tracing: timed span trees + per-stage histograms.
+"""Pipeline tracing: timed spans, per-stage histograms and self times, on
+the profiler's clock.
 
-The attribution layer ISSUE 2 asks for: every pipeline stage (block import,
-processor dispatch, BLS device funnel) runs under `span("stage_name")`. A
-span times itself, nests under whatever span is open on ITS thread, and on
-completion feeds `lighthouse_tpu_stage_seconds{stage=...}` — so the
-Prometheus scrape, the slow-trace ring, and scripts/profile_stages.py all
-report from the same measurements.
+Every pipeline stage (block import, processor dispatch, BLS device funnel)
+runs under `span("stage_name")`. A span times itself, nests under whatever
+span is open on ITS thread, and on completion feeds
+`lighthouse_tpu_stage_seconds{stage=...}` and the tracer's cumulative self
+times (duration minus children), so the Prometheus scrape, the slot ledger
+(common/slot_ledger.py) and scripts/profile_stages.py all report from the
+same measurements.
+
+When jax is loaded, a span also opens a profiler annotation of its name
+(`jax.profiler.TraceAnnotation`). Under a running profiler trace that is an
+event on the span's own thread line of the trace's host plane, on the same
+clock as the device's program and op events; with no trace running it costs
+well under a microsecond. This module never imports jax: nodes on the plain
+backend never load it.
 
 Design constraints:
   - thread-local stacks: the HTTP server, socket receivers, and the drain
     loop each trace independently; spans never cross threads.
-  - completed ROOT spans (no parent) enter a bounded keep-the-N-slowest
-    ring, so "what were the worst block imports" is answerable after the
-    fact without logging every import.
   - exceptions propagate; the span still closes and records its time.
 """
 
 from __future__ import annotations
 
+import sys
 import threading
 import time
 from contextlib import contextmanager
@@ -30,44 +37,27 @@ STAGE_SECONDS = REGISTRY.histogram_vec(
     ("stage",),
 )
 
-SLOW_TRACE_KEEP = 32  # root traces retained by the slowest-ring
-
 
 class Span:
-    __slots__ = ("name", "started_at", "duration", "children")
+    __slots__ = ("name", "started_at", "duration", "child_s")
 
     def __init__(self, name: str):
         self.name = name
         self.started_at = time.perf_counter()
         self.duration: float | None = None  # None while still open
-        self.children: list[Span] = []
-
-    def tree(self) -> dict:
-        """JSON-able {name, duration_s, children} snapshot."""
-        return {
-            "name": self.name,
-            "duration_s": self.duration,
-            "children": [c.tree() for c in self.children],
-        }
-
-    def walk(self):
-        yield self
-        for c in self.children:
-            yield from c.walk()
+        self.child_s = 0.0  # summed durations of the closed child spans
 
 
 class Tracer:
-    def __init__(self, keep: int = SLOW_TRACE_KEEP, stage_histogram=STAGE_SECONDS):
+    def __init__(self, stage_histogram=STAGE_SECONDS):
         self._local = threading.local()
-        self._keep = keep
         self._stage_histogram = stage_histogram
-        self._slowest: list[Span] = []  # sorted slowest-first, len <= keep
         self._lock = threading.Lock()
         # cumulative EXCLUSIVE (self) time per stage: a span's duration
         # minus its children's — non-overlapping within a thread, so
         # windowed deltas sum to at most wall time. The slot-SLO ledger
-        # (common/slot_ledger.py) diffs this dict at slot boundaries;
-        # monotonic by design, so reset() leaves it alone.
+        # (common/slot_ledger.py) diffs this dict at slot boundaries, so it
+        # only ever grows.
         self._self_times: dict[str, float] = {}
 
     def _stack(self) -> list:
@@ -77,38 +67,30 @@ class Tracer:
         return stack
 
     @contextmanager
-    def span(self, name: str):
+    def span(self, name: str, **annotation):
+        """Time the block as stage `name`. `annotation` (key=value) rides on
+        the profiler event alone, e.g. which program a span built."""
         stack = self._stack()
         s = Span(name)
-        if stack:
-            stack[-1].children.append(s)
         stack.append(s)
+        profiler = getattr(sys.modules.get("jax"), "profiler", None)
+        mark = profiler.TraceAnnotation(name, **annotation) if profiler is not None else None
+        if mark is not None:
+            mark.__enter__()
         try:
             yield s
         finally:
+            if mark is not None:
+                mark.__exit__(None, None, None)
             s.duration = time.perf_counter() - s.started_at
             stack.pop()
+            if stack:
+                stack[-1].child_s += s.duration
             self._stage_histogram.labels(stage=name).observe(s.duration)
-            child_s = sum(c.duration or 0.0 for c in s.children)
             with self._lock:
                 self._self_times[name] = self._self_times.get(name, 0.0) + max(
-                    0.0, s.duration - child_s
+                    0.0, s.duration - s.child_s
                 )
-            if not stack:  # a completed root trace
-                self._record_root(s)
-
-    def _record_root(self, root: Span) -> None:
-        with self._lock:
-            ring = self._slowest
-            ring.append(root)
-            ring.sort(key=lambda sp: sp.duration, reverse=True)
-            del ring[self._keep :]
-
-    def slowest(self, n: int | None = None) -> list[dict]:
-        """The slowest completed root traces, slowest first, as trees."""
-        with self._lock:
-            roots = list(self._slowest[: n if n is not None else self._keep])
-        return [r.tree() for r in roots]
 
     def stage_report(self) -> dict[str, dict]:
         """{stage: {count, total_s, mean_s}} from the stage histogram — the
@@ -129,13 +111,6 @@ class Tracer:
         slot ledger attributes a slot by diffing two snapshots."""
         with self._lock:
             return dict(self._self_times)
-
-    def reset(self) -> None:
-        """Drop the slow-trace ring (tests; the stage histogram is owned by
-        the metrics registry and is NOT cleared here; self-times stay —
-        the slot ledger depends on their monotonicity)."""
-        with self._lock:
-            self._slowest.clear()
 
 
 # The process-global tracer; `span("x")` is the instrumentation one-liner.

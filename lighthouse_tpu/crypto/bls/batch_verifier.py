@@ -524,7 +524,8 @@ class BatchVerifier:
                 verdicts = [True] * len(sets)
             else:
                 BLS_BISECTION_BATCHES_TOTAL.inc()
-                verdicts = self._bisect(sets, s_floor=len(sets))
+                with span("bls_bisect"):
+                    verdicts = self._bisect(sets, s_floor=len(sets))
                 BLS_BISECTION_BLAMED_SETS_TOTAL.inc(verdicts.count(False))
         except Exception as exc:  # noqa: BLE001 — re-raised to every caller
             if not is_device_error(exc):

@@ -429,7 +429,11 @@ def verify_stage(key: tuple):
     if name == "keys":
         return _keys_stage, (i32(S, K, 32), i32(S, K, 32), b(S, K), i32(S, 64))
     n = sum(int(np.prod(sh)) for sh, _ in _staged_specs(S, K))
-    return (lambda flat: _unpack_staged(flat, S, K)), (i32(n),)
+
+    def _unpack_stage(flat):  # named: traces and profiles show the jit name
+        return _unpack_staged(flat, S, K)
+
+    return _unpack_stage, (i32(n),)
 
 
 def _lower_stage(key: tuple):
@@ -471,9 +475,37 @@ def _fail_kernel(fut: Future, key: tuple, exc: BaseException) -> None:
     fut.set_exception(exc)
 
 
+def _stage_label(key: tuple) -> str:
+    """A stage key as the profiler annotation of its build: keys-128-4."""
+    return "-".join(map(str, key))
+
+
+# The persistent-cache result of the compile running in this thread: JAX
+# records a cache read as `cache_hits` and a cache write as `cache_misses`,
+# in the compiling thread.
+_compile_cache = threading.local()
+_CACHE_RESULT = {"/jax/compilation_cache/cache_hits": "hit",
+                 "/jax/compilation_cache/cache_misses": "miss"}
+
+
+def _note_cache_event(event: str, **_) -> None:
+    if event in _CACHE_RESULT:
+        _compile_cache.result = _CACHE_RESULT[event]
+
+
+jax.monitoring.register_event_listener(_note_cache_event)
+
+
 def _build_kernel(fut: Future, key: tuple, lowered) -> None:
+    from ....common.metrics import BLS_COMPILE_CACHE_TOTAL
+    from ....common.tracing import span
+
     try:
-        fut.set_result(lowered.compile())
+        _compile_cache.result = "uncached"
+        with span("bls_compile", stage=_stage_label(key)):
+            compiled = lowered.compile()
+        BLS_COMPILE_CACHE_TOTAL.labels(result=_compile_cache.result).inc()
+        fut.set_result(compiled)
     except BaseException as exc:
         _fail_kernel(fut, key, exc)
 
@@ -484,7 +516,14 @@ def compile_verify_kernels(buckets) -> dict[tuple[int, int], float]:
     time in this thread, in bucket order; XLA compiles release it, so up
     to COMPILE_WORKERS compile concurrently. Returns the seconds from this
     call until each bucket's last stage was ready (a persistent-cache hit
-    is a load, not a compile). Raises the first build error."""
+    is a load, not a compile). Raises the first build error.
+
+    Each stage's lowering is a `bls_lower` span in this thread and its XLA
+    compile or cache load a `bls_compile` span in a pool thread; the
+    profiler annotation of each carries the stage key. Each build counts
+    its persistent-cache result (lighthouse_tpu_bls_compile_cache_total)."""
+    from ....common.tracing import span
+
     t0 = time.perf_counter()
     futures: dict[tuple, Future] = {}
     done_at: dict[tuple, float] = {}
@@ -502,7 +541,8 @@ def compile_verify_kernels(buckets) -> dict[tuple[int, int], float]:
                 futures[key] = fut
                 if owner:
                     try:
-                        lowered = _lower_stage(key)
+                        with span("bls_lower", stage=_stage_label(key)):
+                            lowered = _lower_stage(key)
                     except BaseException as exc:
                         _fail_kernel(fut, key, exc)
                         raise
@@ -593,14 +633,26 @@ def stage_sets(sets: list[SignatureSet], rng=None, s_floor: int = 4):
     RLC scalars are drawn/bit-expanded in one batched call. Output is
     byte-identical to the per-element slow path (asserted in
     tests/test_staging.py); the whole call is timed as the `bls_stage`
-    span / lighthouse_tpu_bls_stage_seconds."""
-    from ....common.metrics import BLS_BATCH_PADDED_SIZE, BLS_STAGE_SECONDS
+    span / lighthouse_tpu_bls_stage_seconds, and its S rows and S x K key
+    slots are counted, real and padding
+    (lighthouse_tpu_bls_staged_rows_total, ..._staged_key_slots_total)."""
+    from ....common.metrics import (
+        BLS_BATCH_PADDED_SIZE,
+        BLS_STAGE_SECONDS,
+        BLS_STAGED_KEY_SLOTS_TOTAL,
+        BLS_STAGED_ROWS_TOTAL,
+    )
     from ....common.tracing import span
     from . import h2c
     from .pack import pack_g1_batch, pack_g2_batch
 
     S, K = batch_bucket(len(sets), max(len(s.signing_keys) for s in sets), s_floor)
     BLS_BATCH_PADDED_SIZE.observe(S)
+    n_keys = sum(len(s.signing_keys) for s in sets)
+    BLS_STAGED_ROWS_TOTAL.labels(row="set").inc(len(sets))
+    BLS_STAGED_ROWS_TOTAL.labels(row="pad").inc(S - len(sets))
+    BLS_STAGED_KEY_SLOTS_TOTAL.labels(slot="key").inc(n_keys)
+    BLS_STAGED_KEY_SLOTS_TOTAL.labels(slot="pad").inc(S * K - n_keys)
 
     with BLS_STAGE_SECONDS.time(), span("bls_stage"):
         n = len(sets)
@@ -722,11 +774,16 @@ def verify_signature_sets_async(
     """Submit a batch without waiting for the verdict (see VerifyFuture).
     `s_floor` stages it in a bucket of at least that many sets (the
     coalescer's bisection keeps the failed batch's compiled bucket)."""
+    from ....common.tracing import span
+
     if not _structurally_valid(sets):
         return _INVALID
     staged = stage_sets(sets, rng=rng, s_floor=s_floor)
     kernel = _verify_kernel(*staged[2].shape)
-    return VerifyFuture(kernel(jnp.asarray(_pack_staged(staged))))
+    # the host side of a dispatch's fixed cost: one buffer packed, copied
+    # to the device, and the chain's six programs launched
+    with span("bls_dispatch"):
+        return VerifyFuture(kernel(jnp.asarray(_pack_staged(staged))))
 
 
 def verify_signature_sets(sets: list[SignatureSet], rng=None) -> bool:

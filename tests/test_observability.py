@@ -82,44 +82,59 @@ def test_duplicate_registration_type_conflicts():
 # -- tracing -------------------------------------------------------------------
 
 
+def _totals(stages) -> dict[str, float]:
+    return {k[0]: v.sum for k, v in stages.children().items()}
+
+
 def test_span_tree_nesting_and_stage_histogram():
-    stages = HistogramVec("t_seconds", "", ("stage",))
-    tr = Tracer(keep=2, stage_histogram=stages)
-    with tr.span("root"):
-        with tr.span("child_a"):
-            pass
-        with tr.span("child_b"):
-            with tr.span("grandchild"):
-                pass
-    [tree] = tr.slowest()
-    assert tree["name"] == "root" and tree["duration_s"] > 0
-    assert [c["name"] for c in tree["children"]] == ["child_a", "child_b"]
-    assert tree["children"][1]["children"][0]["name"] == "grandchild"
-    # every span fed the per-stage histogram
-    by_stage = {k[0]: v.count for k, v in stages.children().items()}
-    assert by_stage == {"root": 1, "child_a": 1, "child_b": 1, "grandchild": 1}
-
-
-def test_tracer_keeps_slowest_roots_and_survives_exceptions():
+    """Nesting shows in the self times: a parent's self time is its
+    duration less its children's, never their sum."""
     import time as _t
 
     stages = HistogramVec("t_seconds", "", ("stage",))
-    tr = Tracer(keep=2, stage_histogram=stages)
+    tr = Tracer(stage_histogram=stages)
+    with tr.span("root"):
+        with tr.span("child_a"):
+            _t.sleep(0.002)
+        with tr.span("child_b"):
+            with tr.span("grandchild"):
+                _t.sleep(0.005)
+    # every span fed the per-stage histogram once
+    by_stage = {k[0]: v.count for k, v in stages.children().items()}
+    assert by_stage == {"root": 1, "child_a": 1, "child_b": 1, "grandchild": 1}
+    total, own = _totals(stages), tr.self_time_report()
+    assert own["root"] == pytest.approx(total["root"] - total["child_a"] - total["child_b"])
+    assert own["child_b"] == pytest.approx(total["child_b"] - total["grandchild"])
+    assert own["child_a"] == pytest.approx(total["child_a"])
+    assert own["grandchild"] == pytest.approx(total["grandchild"]) and own["grandchild"] >= 0.005
+    assert own["root"] < 0.005 <= total["root"]
+    assert sum(own.values()) == pytest.approx(total["root"])
+
+
+def test_tracer_keeps_slowest_roots_and_survives_exceptions():
+    """A span that raises still closes and records; the stack unwinds, so
+    the next span is a root whose self time is its whole duration."""
+    import time as _t
+
+    stages = HistogramVec("t_seconds", "", ("stage",))
+    tr = Tracer(stage_histogram=stages)
     for i, sleep in enumerate((0.0, 0.02, 0.001)):
         with pytest.raises(RuntimeError):
             with tr.span(f"r{i}"):
-                _t.sleep(sleep)
-                raise RuntimeError("boom")
-    slow = tr.slowest()
-    assert len(slow) == 2  # ring bounded
-    assert slow[0]["name"] == "r1"  # slowest first
-    assert slow[0]["duration_s"] >= slow[1]["duration_s"]
-    # the stack unwound: a fresh span is a root (recorded, not a child of a
-    # dead span) — it feeds the histogram even when too fast for the ring
+                with tr.span(f"inner{i}"):
+                    _t.sleep(sleep)
+                    raise RuntimeError("boom")
+    total, own = _totals(stages), tr.self_time_report()
+    assert total["inner1"] >= 0.02 and total["r1"] >= total["inner1"]
+    for i in range(3):
+        assert own[f"r{i}"] == pytest.approx(total[f"r{i}"] - total[f"inner{i}"])
+    assert tr._stack() == []
     with tr.span("fresh"):
-        pass
+        _t.sleep(0.001)
     assert {k[0] for k in stages.children()} >= {"r0", "r1", "r2", "fresh"}
-    assert all(not t["children"] for t in tr.slowest())
+    total, own = _totals(stages), tr.self_time_report()
+    assert own["fresh"] == pytest.approx(total["fresh"])  # not a child of a dead span
+    assert own["r2"] == pytest.approx(total["r2"] - total["inner2"])  # r2 took nothing of it
 
 
 # -- processor queue-wait / handle metrics -------------------------------------
@@ -494,7 +509,7 @@ def test_slot_ledger_attribution_sums_to_wall_time():
 
     from lighthouse_tpu.common.slot_ledger import SlotLedger
 
-    tr = Tracer(keep=8, stage_histogram=HistogramVec("sl_seconds", "", ("stage",)))
+    tr = Tracer(stage_histogram=HistogramVec("sl_seconds", "", ("stage",)))
     led = SlotLedger(seconds_per_slot=0.5, tracer=tr)
     led.on_slot(1)
     with tr.span("state_transition"):
@@ -526,7 +541,7 @@ def test_deadline_miss_auto_dumps_correlated_path(tmp_path):
     from lighthouse_tpu.common.flight_recorder import FlightRecorder
     from lighthouse_tpu.common.slot_ledger import SlotLedger
 
-    tr = Tracer(keep=8, stage_histogram=HistogramVec("dm_seconds", "", ("stage",)))
+    tr = Tracer(stage_histogram=HistogramVec("dm_seconds", "", ("stage",)))
     rec = FlightRecorder()
     cid = rec.mint("attestation", node="n0")
     rec.record(cid, "staged", sets=1)
