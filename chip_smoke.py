@@ -16,12 +16,14 @@ One process owns the chip and starts no child. Phases, in order:
 3. Mainnet-shaped batches, through the backend calls the node makes, with
    keys drawn (seeded, distinct within a set) from the node's interop keys:
    a block (128 committee aggregates x 128 keys + proposer and randao sets,
-   staged as S=256, K=128), a gossip batch (128 one-key sets, submitted
-   from several threads so the coalescer forms one S=128 batch; staging
-   pads K to 4) and a 512-key sync aggregate. Valid batches must verify True, the
-   same batches with one set tampered False, and gossip blame must name
-   exactly the tampered set.
-   The device hash-to-G2 program of each batch width S compiled is checked
+   staged as S=256, K=128), a gossip batch (128 one-key sets over 64
+   messages, submitted from several threads so the coalescer forms one
+   S=128 batch; staging pads K to 4 and groups the sets by message, so the
+   device pairs 64 rows) and a 512-key sync aggregate. Valid
+   batches must verify True, the same batches with one set tampered False,
+   and gossip blame must name exactly the tampered set; two gossip sets
+   over one message that swap signatures must be blamed, and no other.
+   The device hash-to-G2 program of each row width compiled is checked
    against the oracle's map, row by row.
 4. Report: device, per-bucket ready/warm seconds (smoke timings, not
    benchmark numbers), kernel builds, peak device bytes, and whether
@@ -50,6 +52,7 @@ T0 = time.perf_counter()
 VALIDATORS = 1024
 NODE_GOSSIP_SLOTS = 4
 BLOCK_SETS = BLOCK_KEYS = GOSSIP_SETS = 128
+GOSSIP_MESSAGES = 64  # a slot's committees: one AttestationData each
 SYNC_KEYS = 512
 
 
@@ -80,6 +83,19 @@ def _tamper(sets, index):
         signing_keys=sets[index].signing_keys,
         message=sets[index].message,
     )
+    return bad
+
+
+def _swap(sets, a, b):
+    """The same batch with sets `a` and `b` carrying each other's
+    signature: their sum is unchanged, so only per-set scalars catch it."""
+    bad = list(sets)
+    for i, j in ((a, b), (b, a)):
+        bad[i] = type(sets[i])(
+            signature=sets[j].signature,
+            signing_keys=sets[i].signing_keys,
+            message=sets[i].message,
+        )
     return bad
 
 
@@ -180,13 +196,19 @@ def block_phase(bls, keys, rng, n_sets: int, k: int):
     return warm
 
 
-def gossip_phase(bls, svc, keys, rng, n_sets: int, threads: int = 8):
+def gossip_phase(bls, svc, keys, rng, n_sets: int, n_messages: int, threads: int = 8):
     """One-key sets from several threads through the running coalescer,
     formed into ONE batch: the device-idle and deadline flushes are held
-    off until every set is queued, then a kick flushes the full bucket."""
+    off until every set is queued, then a kick flushes the full bucket.
+    Set i carries message i % n_messages: with at most n_sets/2 messages
+    the batch is staged with n_sets/2 message rows."""
+    from lighthouse_tpu.common.metrics import BLS_MESSAGE_ROWS_TOTAL
     from lighthouse_tpu.crypto.bls.batch_verifier import mark_device_busy
 
-    messages = [rng.randbytes(32) for _ in range(max(1, n_sets // 32))]
+    def message_rows():
+        return sum(BLS_MESSAGE_ROWS_TOTAL.labels(row=r).value for r in ("distinct", "pad"))
+
+    messages = [rng.randbytes(32) for _ in range(n_messages)]
     members = rng.sample(range(len(keys)), n_sets)
     sets = [_sign_aggregate(bls, keys, [m], messages[i % len(messages)]) for i, m in enumerate(members)]
 
@@ -212,16 +234,26 @@ def gossip_phase(bls, svc, keys, rng, n_sets: int, threads: int = 8):
             svc.max_wait = wait
         return verdicts, svc.dispatches - d0
 
+    rows = message_rows()
     verdicts, dispatches = submit_all(sets)
+    rows = message_rows() - rows
     assert dispatches == 1, f"gossip split into {dispatches} coalesced batches"
     assert all(verdicts), "valid gossip set verified False"
+    want_rows = n_sets // 2 if n_messages <= n_sets // 2 else n_sets
+    assert rows == want_rows, f"gossip batch staged {rows} message rows, not {want_rows}"
     bad = n_sets // 2 + 1
     verdicts, _ = submit_all(_tamper(sets, bad))
     blamed = [i for i, v in enumerate(verdicts) if not v]
     assert blamed == [bad], f"blame {blamed}, tampered {bad}"
+    pair = [3, 3 + n_messages]
+    verdicts, _ = submit_all(_swap(sets, *pair))
+    blamed = [i for i, v in enumerate(verdicts) if not v]
+    assert blamed == pair, f"blame {blamed}, same-message swap {pair}"
     ok, warm = _timed_verify(bls, sets)
     assert ok
-    log(f"gossip: {n_sets} sets in 1 coalesced batch True; tampered set {bad} blamed alone")
+    log(f"gossip: {n_sets} sets over {n_messages} messages in 1 coalesced batch of {rows} "
+        f"message rows True; tampered set {bad} blamed alone; same-message swap {pair} "
+        f"blamed, no other set")
     return warm
 
 
@@ -247,11 +279,12 @@ def sync_phase(bls, keys, rng, k: int):
     return warm
 
 
-def h2c_phase(rng, widths, n_messages: int = 64) -> None:
-    """The device hash-to-G2 program of every batch width the chain
-    compiled, against the oracle's map: `n_messages` seeded messages tiled
-    over the S rows. Fused differently, the TPU compiler once got this map
-    wrong where the CPU was right (h2c.map_to_g2)."""
+def h2c_phase(rng, n_messages: int = 64) -> None:
+    """The device hash-to-G2 program of every row width the chains
+    compiled (each bucket's S; the grouped chains hash at S too), against
+    the oracle's map: `n_messages` seeded messages tiled over the rows.
+    Fused differently, the TPU compiler once got this map wrong where the
+    CPU was right (h2c.map_to_g2)."""
     import jax.numpy as jnp
     import numpy as np
 
@@ -262,9 +295,9 @@ def h2c_phase(rng, widths, n_messages: int = 64) -> None:
 
     messages = [rng.randbytes(32) for _ in range(n_messages)]
     want = [hash_to_g2(m, DST) for m in messages]
-    for S, K in widths:
+    for S in sorted(key[1] for key in list(api._kernels) if key[0] == "h2c"):
         rows = [messages[i % n_messages] for i in range(S)]
-        prog = api.compiled_stage("h2c", S, K)
+        prog = api.compiled_stage(("h2c", S))
         x, y, z = (np.asarray(c) for c in prog(jnp.asarray(h2c.hash_to_field_limbs(rows))))
         bad = []
         for i in range(S):
@@ -337,13 +370,15 @@ def main(argv=None) -> int:
     try:
         warm = {}
         warm[node_bucket] = node_bucket_warm(bls, keys, rng, committee)
-        warm[gossip_bucket] = gossip_phase(bls, client.coalescer, keys, rng, GOSSIP_SETS)
+        warm[gossip_bucket] = gossip_phase(
+            bls, client.coalescer, keys, rng, GOSSIP_SETS, GOSSIP_MESSAGES
+        )
         warm[block_bucket] = block_phase(bls, keys, rng, BLOCK_SETS, BLOCK_KEYS)
         warm[sync_bucket] = sync_phase(bls, keys, rng, SYNC_KEYS)
         compiler.join()
         if errors:
             raise errors[0]
-        h2c_phase(rng, sorted({b[0]: b for b in buckets}.values()))
+        h2c_phase(rng)
         mxu = mxu_matches(args.seed)
     finally:
         client.shutdown()

@@ -378,6 +378,17 @@ BLS_STAGED_KEY_SLOTS_TOTAL = REGISTRY.counter_vec(
     "key, slot=pad is padding",
     ("slot",),
 )
+# Every staged batch, bisection halves included: its message rows, the rows
+# the device pairs (U = S/2 where the batch groups its sets by message, else
+# S). Over ..._staged_rows_total it reads 0.5 where every
+# batch groups and 1.0 where none does.
+BLS_MESSAGE_ROWS_TOTAL = REGISTRY.counter_vec(
+    "lighthouse_tpu_bls_message_rows_total",
+    "Message rows of staged verify batches: row=distinct holds one of the "
+    "batch's distinct messages, row=pad repeats one (a repeated or padding "
+    "set's row, or an unused row of a grouped batch)",
+    ("row",),
+)
 
 # Host staging fast path (stage_sets): per-point packed-limb caching and
 # hash-to-curve dedup/LRU. Labels: cache="pk_limbs" (G1 pubkey limb rows,

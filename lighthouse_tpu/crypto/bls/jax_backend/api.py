@@ -10,7 +10,9 @@ programs per (batch-size, keys-per-set) bucket (_VERIFY_CHAIN):
             check — deferred to the device), RLC scalar sampling, packing
     device: SSWU/isogeny/cofactor hash-to-G2; psi-criterion subgroup checks
             for every signature; G1 ladders for r_i * aggpk_i; G2 ladders
-            for r_i * sig_i; n+1 Miller loops; one final exponentiation
+            for r_i * sig_i; n+1 Miller loops; one final exponentiation.
+            A batch with at most S/2 distinct messages sums the r_i * aggpk_i
+            of each message first, and pairs S/2 rows (_GROUPED_CHAIN)
 
 Semantics match the reference exactly (impls/blst.rs:36-119):
   - independent nonzero 64-bit scalars per set (RAND_BITS = 64)
@@ -41,6 +43,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from ..constants import DST
 from ..ref import api as _ref
@@ -278,6 +281,37 @@ def _sigs_stage(sig_x, sig_y, sig_inf, r_bits):
     return _tree_fold(FP2, rsig, axis=0), jnp.all(sub_ok)
 
 
+def _group_sums(r_pk, group, U: int):
+    """G1 side of the grouped chain: the S points r_i * aggpk_i summed per
+    message group into U points, one per staged message row. The host
+    plan (_message_plan) orders the rows so each group is contiguous and
+    numbers the groups 0, 1, ... in row order.
+
+    A segmented inclusive scan (Hillis-Steele) over the S lanes: log2(S)
+    steps, each one complete addition per lane, which adds the lane d = 2^k
+    back only when it holds the same group; the steps are one loop, so the
+    addition compiles once. A group's sum is then its last lane; a group
+    with no lane (an unused message row) is infinity, so its Miller pair
+    is 1."""
+    from .curve import FP, Proj, _sel, add, infinity
+
+    S = group.shape[0]
+    lane = jnp.arange(S, dtype=jnp.int32)
+    inf = infinity(FP, (S,))
+
+    def step(k, p):
+        d = jnp.left_shift(jnp.int32(1), k)
+        back = Proj(*(jnp.roll(c, d, axis=0) for c in p))
+        same = (lane >= d) & (jnp.roll(group, d) == group)
+        return add(FP, p, _sel(FP, same, back, inf))
+
+    p = lax.fori_loop(0, S.bit_length() - 1, step, r_pk)
+    rows = jnp.arange(U, dtype=jnp.int32)
+    last = jnp.max(jnp.where(group[None, :] == rows[:, None], lane[None, :], -1), axis=1)
+    sums = Proj(*(jnp.take(c, last, axis=0, mode="clip") for c in p))
+    return _sel(FP, last >= 0, sums, infinity(FP, (U,)))
+
+
 def _pairs_stage(r_pk, H, sig_acc):
     """S+1 Miller pairs: (r_i aggpk_i, H_i) and (-g1, local sig_acc),
     reduced to one Fp12 partial product.
@@ -351,18 +385,23 @@ def verify_pipeline_local(pk_x, pk_y, pk_inf, sig_x, sig_y, sig_inf, u, r_bits):
     return partial, sub_ok & ~agg_inf
 
 
-def _staged_specs(S: int, K: int):
-    """(shape, is_bool) of each staged array, in stage_sets order."""
-    return [
+def _staged_specs(S: int, K: int, U: int | None = None):
+    """(shape, is_bool) of each staged array, in stage_sets order; with U
+    message rows (the grouped chain), u has U rows and each set's group
+    index follows."""
+    specs = [
         ((S, K, 32), False),  # pk_x
         ((S, K, 32), False),  # pk_y
         ((S, K), True),  # pk_inf
         ((S, 2, 32), False),  # sig_x
         ((S, 2, 32), False),  # sig_y
         ((S,), True),  # sig_inf
-        ((S, 2, 2, 32), False),  # u
+        ((S if U is None else U, 2, 2, 32), False),  # u
         ((S, 64), False),  # r_bits
     ]
+    if U is not None:
+        specs.append(((S,), False))  # group
+    return specs
 
 
 def _pack_staged(staged) -> np.ndarray:
@@ -372,13 +411,15 @@ def _pack_staged(staged) -> np.ndarray:
     return np.concatenate([np.ravel(np.asarray(a)).astype(np.int32) for a in staged])
 
 
-def _unpack_staged(flat, S: int, K: int):
+def _unpack_staged(flat, S: int, K: int, U: int | None = None):
     out, off = [], 0
-    for shape, is_bool in _staged_specs(S, K):
+    for shape, is_bool in _staged_specs(S, K, U):
         n = int(np.prod(shape))
         a = flat[off : off + n].reshape(shape)
         out.append(a.astype(bool) if is_bool else a)
         off += n
+    if U is not None:  # the grouped chain hashes its U rows at S-row width
+        out[6] = jnp.concatenate([out[6]] * (S // U))
     return tuple(out)
 
 
@@ -393,17 +434,52 @@ def _unpack_staged(flat, S: int, K: int):
 #
 # Hash-to-G2 is a stage of its own, with a barrier inside (h2c.map_to_g2):
 # fused whole, the TPU compiler got the map's y wrong (my chip runs, PR 21).
+#
+# Each bucket with S >= GROUP_MIN_S has a second chain, for batches whose
+# sets carry at most U = S/2 distinct messages (gossip attestations share a
+# committee's AttestationData): by bilinearity
+#     prod_i e(r_i pk_i, H(m_i)) = prod_m e(sum_{i: m_i = m} r_i pk_i, H(m)),
+# so the `group` stage sums the G1 side per message and the Miller loop runs
+# over U rows: the very pairs program of a (U, K) bucket. Every set keeps its
+# own scalar r_i, and sigs still checks all S signatures. Hash-to-G2 stays
+# the S-row program, over the U message rows tiled S/U times, and `group`
+# keeps its first U points: on a v5e the program compiled for 64 rows gave
+# wrong points for most of the grouped chain's rows, where the 128-row
+# program, fed the same messages, was right.
 
 _VERIFY_CHAIN = ("unpack", "keys", "sigs", "h2c", "pairs", "final")
+_GROUPED_CHAIN = ("unpack", "keys", "sigs", "h2c", "group", "pairs", "final")
+GROUP_MIN_S = 8
 
 
-def _stage_key(name: str, S: int, K: int) -> tuple:
-    """The shapes a stage program depends on: (S, K), S alone, or none."""
-    if name in ("unpack", "keys"):
+def _stage_key(name: str, S: int, K: int, U: int | None = None) -> tuple:
+    """The shapes a stage program depends on: (S, K), S alone, or none; in
+    the grouped chain (U message rows) the unpack program also U, group
+    (S, U), and pairs U alone."""
+    if name == "unpack":
+        return (name, S, K) if U is None else (name, S, K, U)
+    if name == "keys":
         return (name, S, K)
     if name == "final":
         return (name,)
+    if name == "group":
+        return (name, S, U)
+    if name == "pairs" and U is not None:
+        return (name, U)
     return (name, S)
+
+
+def _chain_keys(S: int, K: int, U: int | None = None) -> list[tuple]:
+    """The stage keys of bucket (S, K)'s full chain, or of its grouped
+    chain over U message rows."""
+    names = _VERIFY_CHAIN if U is None else _GROUPED_CHAIN
+    return [_stage_key(name, S, K, U) for name in names]
+
+
+def _bucket_chains(S: int, K: int) -> list[tuple]:
+    """The chains a bucket is built with: (S, K), and (S, K, S/2) where
+    the grouped chain exists."""
+    return [(S, K)] + ([(S, K, S // 2)] if S >= GROUP_MIN_S else [])
 
 
 def verify_stage(key: tuple):
@@ -422,16 +498,26 @@ def verify_stage(key: tuple):
         from .h2c import hash_to_g2_device
 
         return hash_to_g2_device, (i32(S, 2, 2, 32),)
+    g1 = Proj(*[i32(S, 32)] * 3)
     if name == "pairs":
-        g1, g2 = Proj(*[i32(S, 32)] * 3), Proj(*[i32(S, 2, 32)] * 3)
+        g2 = Proj(*[i32(S, 2, 32)] * 3)
         return _pairs_stage, (g1, g2, Proj(*[i32(2, 32)] * 3))
+    if name == "group":
+        U = shape[1]
+
+        def _group_stage(r_pk, group, H):  # named: traces and profiles show the jit name
+            # H: the S-row hash of the tiled message rows; its first U are theirs
+            return _group_sums(r_pk, group, U), Proj(*(c[:U] for c in H))
+
+        return _group_stage, (g1, i32(S), Proj(*[i32(S, 2, 32)] * 3))
     K = shape[1]
     if name == "keys":
         return _keys_stage, (i32(S, K, 32), i32(S, K, 32), b(S, K), i32(S, 64))
-    n = sum(int(np.prod(sh)) for sh, _ in _staged_specs(S, K))
+    U = shape[2] if len(shape) > 2 else None
+    n = sum(int(np.prod(sh)) for sh, _ in _staged_specs(S, K, U))
 
     def _unpack_stage(flat):  # named: traces and profiles show the jit name
-        return _unpack_staged(flat, S, K)
+        return _unpack_staged(flat, S, K, U)
 
     return _unpack_stage, (i32(n),)
 
@@ -512,9 +598,12 @@ def _build_kernel(fut: Future, key: tuple, lowered) -> None:
 
 def compile_verify_kernels(buckets) -> dict[tuple[int, int], float]:
     """Build the stage programs of the given (S, K) buckets ahead of their
-    first batch. Tracing holds the GIL, so stages are lowered one at a
-    time in this thread, in bucket order; XLA compiles release it, so up
-    to COMPILE_WORKERS compile concurrently. Returns the seconds from this
+    first batch: each bucket's full chain and, where S >= GROUP_MIN_S, its
+    grouped chain. Tracing holds the GIL, so stages are lowered one at a
+    time in this thread, the full chains' in bucket order and then the
+    grouped chains' (their own programs load or compile while the last
+    full-chain programs still do); XLA compiles release it, so up to
+    COMPILE_WORKERS compile concurrently. Returns the seconds from this
     call until each bucket's last stage was ready (a persistent-cache hit
     is a load, not a compile). Raises the first build error.
 
@@ -531,10 +620,11 @@ def compile_verify_kernels(buckets) -> dict[tuple[int, int], float]:
     def note(key):
         return lambda _fut: done_at.setdefault(key, time.perf_counter() - t0)
 
+    chains = [chain for S, K in buckets for chain in _bucket_chains(S, K)]
+    chains.sort(key=len)  # stable: the full chains (S, K) first
     with ThreadPoolExecutor(max_workers=COMPILE_WORKERS) as pool:
-        for S, K in buckets:
-            for name in _VERIFY_CHAIN:
-                key = _stage_key(name, S, K)
+        for chain in chains:
+            for key in _chain_keys(*chain):
                 if key in futures:
                     continue
                 fut, owner = _claim_kernel(key)
@@ -550,39 +640,48 @@ def compile_verify_kernels(buckets) -> dict[tuple[int, int], float]:
                 fut.add_done_callback(note(key))
     ready = {}
     for S, K in buckets:
-        keys = [_stage_key(name, S, K) for name in _VERIFY_CHAIN]
+        keys = [key for chain in _bucket_chains(S, K) for key in _chain_keys(*chain)]
         for key in keys:
             futures[key].result()
         ready[(S, K)] = max(done_at[key] for key in keys)
     return ready
 
 
-def compiled_stage(name: str, S: int, K: int):
-    """Stage program `name` of bucket (S, K)'s chain, compiled (or built
-    now). chip_smoke.py checks the device hash-to-G2 with it."""
-    compile_verify_kernels([(S, K)])
-    return _kernels[_stage_key(name, S, K)].result()
+def compiled_stage(key: tuple):
+    """The stage program of a stage key that compile_verify_kernels has
+    built. chip_smoke.py checks the device hash-to-G2 of each width with
+    it."""
+    return _kernels[key].result()
 
 
-def _verify_kernel(S: int, K: int):
-    """The batch-verify chain for bucket (S, K): flat staged buffer (see
-    _pack_staged) -> device bool, dispatched stage by stage without a host
-    sync."""
-    run = _chains.get((S, K))
+def _verify_kernel(S: int, K: int, U: int | None = None):
+    """The batch-verify chain for bucket (S, K), or its grouped chain over
+    U message rows: flat staged buffer (see _pack_staged) -> device bool,
+    dispatched stage by stage without a host sync."""
+    chain = (S, K) if U is None else (S, K, U)
+    run = _chains.get(chain)
     if run is not None:
         return run
     compile_verify_kernels([(S, K)])
-    unpack, keys, sigs, h2c, pairs, final = (
-        _kernels[_stage_key(name, S, K)].result() for name in _VERIFY_CHAIN
-    )
+    stages = [_kernels[key].result() for key in _chain_keys(*chain)]
+    if U is None:
+        unpack, keys, sigs, h2c, pairs, final = stages
 
-    def run(flat):
-        pk_x, pk_y, pk_inf, sig_x, sig_y, sig_inf, u, r_bits = unpack(flat)
-        r_pk, agg_inf = keys(pk_x, pk_y, pk_inf, r_bits)
-        sig_acc, sub_ok = sigs(sig_x, sig_y, sig_inf, r_bits)
-        return final(pairs(r_pk, h2c(u), sig_acc), sub_ok, agg_inf)
+        def run(flat):
+            pk_x, pk_y, pk_inf, sig_x, sig_y, sig_inf, u, r_bits = unpack(flat)
+            r_pk, agg_inf = keys(pk_x, pk_y, pk_inf, r_bits)
+            sig_acc, sub_ok = sigs(sig_x, sig_y, sig_inf, r_bits)
+            return final(pairs(r_pk, h2c(u), sig_acc), sub_ok, agg_inf)
+    else:
+        unpack, keys, sigs, h2c, group, pairs, final = stages
 
-    _chains[(S, K)] = run
+        def run(flat):
+            pk_x, pk_y, pk_inf, sig_x, sig_y, sig_inf, u, r_bits, grp = unpack(flat)
+            r_pk, agg_inf = keys(pk_x, pk_y, pk_inf, r_bits)
+            sig_acc, sub_ok = sigs(sig_x, sig_y, sig_inf, r_bits)
+            return final(pairs(*group(r_pk, grp, h2c(u)), sig_acc), sub_ok, agg_inf)
+
+    _chains[chain] = run
     return run
 
 
@@ -621,6 +720,34 @@ def _batched_nonzero_scalars(n: int) -> np.ndarray:
         out[zeros] = np.frombuffer(secrets.token_bytes(8 * zeros.size), dtype=np.uint64)
 
 
+def _message_plan(messages: list[bytes], S: int):
+    """How a batch of S rows whose sets carry `messages` groups by message:
+    None where it takes the full chain (U = S: S < GROUP_MIN_S, or more
+    than S/2 distinct messages), else (order, group, rows) for the grouped
+    chain's U = S/2 message rows:
+
+    - order (S,): the staged rows' new order, the real sets stably sorted
+      by group so each group is contiguous, the padding rows after them;
+    - group (S,) int32: each reordered row's group, numbered by first
+      appearance; padding rows (r = 0, identity) join the last group and
+      take no message row;
+    - rows: the U messages, the distinct ones in group order, the unused
+      rows repeating the first (their G1 sum is infinity: Miller value 1)."""
+    ids: dict[bytes, int] = {}
+    for m in messages:
+        ids.setdefault(m, len(ids))
+    U = S // 2
+    if S < GROUP_MIN_S or len(ids) > U:
+        return None
+    n = len(messages)
+    gid = np.fromiter((ids[m] for m in messages), dtype=np.int32, count=n)
+    order = np.concatenate([np.argsort(gid, kind="stable"), np.arange(n, S)])
+    group = np.full(S, len(ids) - 1, dtype=np.int32)
+    group[:n] = gid[order[:n]]
+    rows = list(ids) + [messages[0]] * (U - len(ids))
+    return order, group, rows
+
+
 def stage_sets(sets: list[SignatureSet], rng=None, s_floor: int = 4):
     """Host staging for the device kernels: pad the batch to the S bucket
     (pow2, >= s_floor) with (generator-keyed, r=0) no-op sets and each key
@@ -633,11 +760,26 @@ def stage_sets(sets: list[SignatureSet], rng=None, s_floor: int = 4):
     RLC scalars are drawn/bit-expanded in one batched call. Output is
     byte-identical to the per-element slow path (asserted in
     tests/test_staging.py); the whole call is timed as the `bls_stage`
-    span / lighthouse_tpu_bls_stage_seconds, and its S rows and S x K key
-    slots are counted, real and padding
-    (lighthouse_tpu_bls_staged_rows_total, ..._staged_key_slots_total)."""
+    span / lighthouse_tpu_bls_stage_seconds, and its S rows, S x K key
+    slots and message rows are counted, real and padding
+    (lighthouse_tpu_bls_staged_rows_total, ..._staged_key_slots_total,
+    ..._message_rows_total)."""
+    return _stage(sets, rng, s_floor, plan=False)[0]
+
+
+def stage_sets_grouped(sets: list[SignatureSet], rng=None, s_floor: int = 4):
+    """The dispatch's staging: stage_sets, with the message plan
+    (_message_plan) made inside the same `bls_stage` span. Returns
+    (staged, group): stage_sets' arrays and None where the batch takes the
+    full chain; else those arrays in the plan's order, u holding the U
+    message rows, and the (S,) int32 group index of each row."""
+    return _stage(sets, rng, s_floor, plan=True)
+
+
+def _stage(sets, rng, s_floor: int, plan: bool):
     from ....common.metrics import (
         BLS_BATCH_PADDED_SIZE,
+        BLS_MESSAGE_ROWS_TOTAL,
         BLS_STAGE_SECONDS,
         BLS_STAGED_KEY_SLOTS_TOTAL,
         BLS_STAGED_ROWS_TOTAL,
@@ -698,9 +840,20 @@ def stage_sets(sets: list[SignatureSet], rng=None, s_floor: int = 4):
             pk_y = pk_y.reshape(S, K, -1)
             pk_inf = pk_inf.reshape(S, K)
             sig_x, sig_y, sig_inf = pack_g2_batch(sig_pts)
+        grouping = _message_plan(msgs[:n], S) if plan else None
         with span("bls_h2c_host"):
-            u = h2c.hash_to_field_limbs(msgs)
-    return pk_x, pk_y, pk_inf, sig_x, sig_y, sig_inf, u, r_rows
+            u = h2c.hash_to_field_limbs(msgs if grouping is None else grouping[2])
+        group = None
+        if grouping is not None:
+            order, group, _ = grouping
+            pk_x, pk_y, pk_inf, sig_x, sig_y, sig_inf, r_rows = (
+                a[order] for a in (pk_x, pk_y, pk_inf, sig_x, sig_y, sig_inf, r_rows)
+            )
+    staged = (pk_x, pk_y, pk_inf, sig_x, sig_y, sig_inf, u, r_rows)
+    distinct = len(set(msgs[:n]))
+    BLS_MESSAGE_ROWS_TOTAL.labels(row="distinct").inc(distinct)
+    BLS_MESSAGE_ROWS_TOTAL.labels(row="pad").inc(len(u) - distinct)
+    return staged, group
 
 
 def drop_staging_caches(sets) -> None:
@@ -778,10 +931,14 @@ def verify_signature_sets_async(
 
     if not _structurally_valid(sets):
         return _INVALID
-    staged = stage_sets(sets, rng=rng, s_floor=s_floor)
-    kernel = _verify_kernel(*staged[2].shape)
+    staged, group = stage_sets_grouped(sets, rng=rng, s_floor=s_floor)
+    if group is None:
+        kernel = _verify_kernel(*staged[2].shape)
+    else:
+        kernel = _verify_kernel(*staged[2].shape, len(staged[6]))
+        staged = (*staged, group)
     # the host side of a dispatch's fixed cost: one buffer packed, copied
-    # to the device, and the chain's six programs launched
+    # to the device, and the chain's programs launched
     with span("bls_dispatch"):
         return VerifyFuture(kernel(jnp.asarray(_pack_staged(staged))))
 
@@ -900,6 +1057,23 @@ def _verify_pipeline_spec(S: int, K: int):
         _reg.LIMB, _reg.BIT,
     ]
     return verify_pipeline_local, args, ranges
+
+
+@_reg.register("api.group_sums@S128U64")
+def _spec_group_sums():
+    """The grouped chain's `group` stage at the gossip bucket's widths: S
+    keys-stage points, each with its message group in [0, U)."""
+    from .curve import FP, from_affine
+    from .fp import N_LIMBS
+
+    S, U = 128, 64
+
+    def fn(x, y, inf, group):
+        return _group_sums(from_affine(FP, x, y, inf), group, U)
+
+    x = np.zeros((S, N_LIMBS), np.int32)
+    args = (x, x.copy(), np.zeros(S, bool), np.zeros(S, np.int32))
+    return fn, args, [_reg.LIMB, _reg.LIMB, _reg.BOOL, (0, U - 1)]
 
 
 @_reg.register("api.verify_pipeline_local@S4K4", tier="slow")

@@ -127,8 +127,10 @@ def test_each_stage_build_is_a_lower_and_a_compile_span(monkeypatch, fresh_trace
     monkeypatch.setattr(api, "_lower_stage", lambda key: _Lowered())
     monkeypatch.setattr(api, "_kernels", {})
     api.compile_verify_kernels([(4, 4), (8, 4)])
-    # (8, 4) shares only the shape-free final stage with (4, 4)
-    n = 2 * len(api._VERIFY_CHAIN) - 1
+    # (8, 4) shares only the shape-free final stage with (4, 4); its grouped
+    # chain (U = 4) adds its own unpack and group programs, and reuses
+    # (4, 4)'s pairs and its own full chain's h2c
+    n = 2 * len(api._VERIFY_CHAIN) - 1 + 2
     assert _counts(fresh_tracer) == {"bls_compile": n, "bls_lower": n}
     api.compile_verify_kernels([(4, 4)])  # built already: no span
     assert _counts(fresh_tracer) == {"bls_compile": n, "bls_lower": n}

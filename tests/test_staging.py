@@ -350,10 +350,12 @@ def test_verify_stages_build_once_across_threads_and_buckets(monkeypatch):
     assert len(got) == 2 and got[1] is japi._verify_kernel(4, 1)
     assert len(builds) == len(japi._VERIFY_CHAIN)
 
-    # (4, 64) shares the S-only and shape-free stages; (16, 4) final only
+    # (4, 64) shares the S-only and shape-free stages; (16, 4) final only,
+    # and its grouped chain (U = 8) adds unpack, group and pairs
     ready = japi.compile_verify_kernels([(4, 64), (16, 4)])
     assert set(ready) == {(4, 64), (16, 4)}
-    assert len(builds) == len(japi._VERIFY_CHAIN) + 2 + 5
+    assert len(builds) == len(japi._VERIFY_CHAIN) + 2 + 5 + 3
+    assert lowered.index(("pairs", 16)) < lowered.index(("unpack", 16, 4, 8))
     with pytest.raises(RuntimeError, match="compile refused"):
         japi.compile_verify_kernels([(8, 2)])
     assert ("keys", 8, 2) not in japi._kernels

@@ -86,18 +86,29 @@ def test_kernel_compiles_for_one_v5e_chip(one_chip, name, batch, lead):
     assert 0 < _device_bytes(compiled) < HBM_BYTES
 
 
+def _compile_stage(one_chip, key):
+    fn, specs = api.verify_stage(key)
+    shaped = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip), specs
+    )
+    return jax.jit(fn).lower(*shaped).compile()
+
+
+def test_group_stage_compiles_for_one_v5e_chip(one_chip):
+    """The grouped chain's own stage at the gossip bucket: 128 sets summed
+    into 64 message rows."""
+    compiled = _compile_stage(one_chip, ("group", 128, 64))
+    assert 0 < _device_bytes(compiled) < HBM_BYTES
+
+
 @pytest.mark.slow
 @pytest.mark.parametrize(
-    "bucket",
-    [(S, K), api.batch_bucket(128, 1)],
-    ids=["block_256x128", "gossip_128x4"],
+    "chain",
+    [(S, K), api.batch_bucket(128, 1), (*api.batch_bucket(128, 1), 64)],
+    ids=["block_256x128", "gossip_128x4", "gossip_128x4_grouped_64"],
 )
-def test_verify_stages_compile_for_one_v5e_chip(one_chip, bucket):
-    """Every stage program of a bucket's verify chain (api._VERIFY_CHAIN)."""
-    for name in api._VERIFY_CHAIN:
-        fn, specs = api.verify_stage(api._stage_key(name, *bucket))
-        shaped = jax.tree.map(
-            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip), specs
-        )
-        compiled = jax.jit(fn).lower(*shaped).compile()
-        assert _device_bytes(compiled) < HBM_BYTES, name
+def test_verify_stages_compile_for_one_v5e_chip(one_chip, chain):
+    """Every stage program of a bucket's verify chain (api._VERIFY_CHAIN),
+    or of its grouped chain (api._GROUPED_CHAIN: h2c and pairs at U rows)."""
+    for key in api._chain_keys(*chain):
+        assert _device_bytes(_compile_stage(one_chip, key)) < HBM_BYTES, key
