@@ -73,7 +73,8 @@ SLOT_VALIDATORS_SUPPORTABLE = REGISTRY.gauge(
 # (wall minus everything traced) lands in unattributed. bls_dispatch (pack,
 # host-to-device copy, launches) nests in bls_batch_verify and counts as
 # device_execute, as it did as that span's self time; bls_bisect (the
-# resolver's blame: its halves' dispatches and verdict waits) too.
+# resolver's blame: its halves' dispatches and verdict waits) and bls_fetch
+# (the verdict's copy to the host, inside bls_device_execute) too.
 STAGE_OF_SPAN = {
     "processor_handle_gossip_attestation": "gossip_admission",
     "processor_handle_gossip_aggregate": "gossip_admission",
@@ -85,6 +86,7 @@ STAGE_OF_SPAN = {
     "bls_batch_verify": "device_execute",
     "bls_device_execute": "device_execute",
     "bls_dispatch": "device_execute",
+    "bls_fetch": "device_execute",
     "bls_bisect": "device_execute",
     "state_transition": "state_transition",
     "signature_verify": "state_transition",

@@ -112,10 +112,11 @@ G2_GEN_Y_L = pack_fp2(*G2_GENERATOR_Y)
 #
 # The batch packers are the host staging hot path (stage_sets,
 # batch_validate_public_keys). Two levers keep them off the profile:
-#   - limb rows are memoized on the Point object itself (the `_limbs` slot):
-#     a validator pubkey held by the PubkeyCache is packed once per process
-#     lifetime, a signature re-staged by bisection is packed once per batch
-#     failure — later stagings GATHER the rows instead of re-deriving them.
+#   - limb rows are memoized on the Point object itself (the `_limbs` slot,
+#     the bytes of its int32 (x, y) rows): a validator pubkey held by the
+#     PubkeyCache is packed once per process lifetime, a signature re-staged
+#     by bisection is packed once per batch failure — later stagings GATHER
+#     the rows instead of re-deriving them.
 #   - cache misses are converted in ONE `fp.to_mont_host_bulk` call (the
 #     per-int Python shift/mask loop was ~10x the bigint mulmod cost).
 # Output is byte-identical to stacking the per-point pack_g1/pack_g2 results.
@@ -133,44 +134,30 @@ def _count_staging_cache(cache: str, hits: int, misses: int) -> None:
 
 
 def _pack_batch(pts, row_shape, coords_of, split_rows, label):
-    # preallocate and direct-assign rather than np.stack a row list — zeros
-    # double as the infinity rows, and stack's per-element introspection was
-    # the warm-path hotspot
-    xs = np.zeros((len(pts), *row_shape), dtype=np.int32)
-    ys = np.zeros_like(xs)
-    infs = np.zeros(len(pts), dtype=bool)
+    # A point's rows are kept as the bytes of its (2, *row_shape) int32
+    # array (x, y), and an infinity point's are zeros: the batch's rows are
+    # ONE bytes join, ~7x cheaper than assigning or concatenating per-point
+    # arrays (a block's ~18,700 keys on a CPU host: ~2 ms against ~14). The
+    # x and y returned are read-only views of that buffer.
+    zero = bytes(8 * int(np.prod(row_shape)))
+    rows = [zero if pt.inf else getattr(pt, "_limbs", None) for pt in pts]
     miss: dict[int, list[int]] = {}  # id(pt) -> positions (dedup in-batch)
-    hits = 0
-    for i, pt in enumerate(pts):
-        if pt.inf:
-            infs[i] = True
-            continue
-        rows = getattr(pt, "_limbs", None)
-        if rows is None:
-            miss.setdefault(id(pt), []).append(i)
-        else:
-            hits += 1
-            xs[i] = rows[0]
-            ys[i] = rows[1]
+    for i in [i for i, r in enumerate(rows) if r is None]:
+        miss.setdefault(id(pts[i]), []).append(i)
     if miss:
         coords: list[int] = []
         for idxs in miss.values():
             coords.extend(coords_of(pts[idxs[0]]))
         limbs = fp.to_mont_host_bulk(coords)
         for k, idxs in enumerate(miss.values()):
-            # copy out of the batch-sized bulk array: the rows live as long
-            # as the Point (a cached pubkey pins them for the process
-            # lifetime) and must not keep the whole batch's limbs alive
-            x_row, y_row = (r.copy() for r in split_rows(limbs, k))
-            x_row.setflags(write=False)
-            y_row.setflags(write=False)
-            pts[idxs[0]]._limbs = (x_row, y_row)
+            r = np.stack(split_rows(limbs, k)).tobytes()
+            pts[idxs[0]]._limbs = r
             for i in idxs:
-                xs[i] = x_row
-                ys[i] = y_row
-        hits += sum(len(v) - 1 for v in miss.values())
-    _count_staging_cache(label, hits, len(miss))
-    return xs, ys, infs
+                rows[i] = r
+    xy = np.frombuffer(b"".join(rows), dtype=np.int32).reshape(len(pts), 2, *row_shape)
+    infs = np.fromiter((pt.inf for pt in pts), dtype=bool, count=len(pts))
+    _count_staging_cache(label, len(pts) - int(infs.sum()) - len(miss), len(miss))
+    return xy[:, 0], xy[:, 1], infs
 
 
 def pack_g1_batch(pts: list[Point]) -> tuple[np.ndarray, np.ndarray, np.ndarray]:

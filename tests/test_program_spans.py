@@ -234,6 +234,35 @@ def test_bisection_halves_are_staged_counted_and_spanned(monkeypatch, fresh_trac
     assert counts["bls_device_execute"] == 1
 
 
+def test_a_direct_batch_times_the_verdict_copy_apart_from_the_device_wait(monkeypatch,
+                                                                          fresh_tracer):
+    """verify_signature_sets waits for the chain on the device, then copies
+    the verdict to the host under bls_fetch, inside bls_device_execute."""
+    from lighthouse_tpu.common.slot_ledger import STAGE_OF_SPAN
+    from lighthouse_tpu.crypto.bls.jax_backend import api
+
+    calls = []
+
+    class Verdict:
+        def block_until_ready(self):
+            calls.append("ready")
+            return self
+
+        def __bool__(self):
+            calls.append("copy")
+            return True
+
+    monkeypatch.setattr(api, "_verify_kernel", lambda S, K: (lambda flat: Verdict()))
+    assert api.verify_signature_sets(_sets([1, 2])) is True
+    assert calls == ["ready", "copy"]
+    counts = _counts(fresh_tracer)
+    assert counts["bls_device_execute"] == counts["bls_fetch"] == 1
+    own, total = fresh_tracer.self_time_report(), fresh_tracer.stage_report()
+    assert own["bls_device_execute"] == pytest.approx(  # bls_fetch is its child
+        total["bls_device_execute"]["total_s"] - total["bls_fetch"]["total_s"])
+    assert STAGE_OF_SPAN["bls_fetch"] == "device_execute"
+
+
 def test_the_slot_ledger_counts_dispatch_and_bisection_as_device_execution():
     """bls_dispatch's self time stays where it was counted as part of
     bls_batch_verify's; the stage-program builds fall to other_traced."""

@@ -23,6 +23,7 @@ from lighthouse_tpu.crypto import bls
 from lighthouse_tpu.crypto.bls.constants import DST, G1_GENERATOR_X, P
 from lighthouse_tpu.crypto.bls.jax_backend import api as japi, fp, h2c, pack
 from lighthouse_tpu.crypto.bls.ref import hash_to_curve as ref_h2c
+from lighthouse_tpu.crypto.bls.ref.curves import g1_infinity
 
 
 def _chill(sets) -> None:
@@ -210,6 +211,13 @@ def test_stage_sets_metrics_move_cold_to_warm(sets):
     assert BLS_STAGE_SECONDS.count == n_stage + 2  # every staging is timed
 
 
+def _limb_rows(pt):
+    """A point's memoized (x, y) limb rows (pack.py keeps their bytes), or
+    None where it has none."""
+    rows = getattr(pt, "_limbs", None)
+    return None if rows is None else np.frombuffer(rows, dtype=np.int32).reshape(2, fp.N_LIMBS)
+
+
 def test_mutated_pubkey_bytes_cannot_serve_stale_limbs(jax_bls):
     """The PubkeyCache keys on (index, pubkey-bytes): mutate a validator's
     pubkey in the state and the resolver must hand back a fresh point whose
@@ -233,7 +241,7 @@ def test_mutated_pubkey_bytes_cannot_serve_stale_limbs(jax_bls):
 
     first = cache.resolver(state)(0)
     assert first is not None
-    rows_a = getattr(first.point, "_limbs", None)
+    rows_a = _limb_rows(first.point)
     assert rows_a is not None, "resolver must precompute limb rows (jax backend)"
     assert np.array_equal(rows_a[0], pack.pack_fp(pk_a.point.x.n))
 
@@ -243,7 +251,7 @@ def test_mutated_pubkey_bytes_cannot_serve_stale_limbs(jax_bls):
     state.validators[0].pubkey = pk_b.to_bytes()
     second = cache.resolver(state)(0)
     assert second is not None and second is not first
-    rows_b = getattr(second.point, "_limbs", None)
+    rows_b = _limb_rows(second.point)
     assert rows_b is not None
     assert np.array_equal(rows_b[0], pack.pack_fp(pk_b.point.x.n))
     assert not np.array_equal(rows_b[0], rows_a[0])
@@ -359,6 +367,50 @@ def test_verify_stages_build_once_across_threads_and_buckets(monkeypatch):
     with pytest.raises(RuntimeError, match="compile refused"):
         japi.compile_verify_kernels([(8, 2)])
     assert ("keys", 8, 2) not in japi._kernels
+
+
+def test_ragged_batch_packs_only_real_keys_as_the_slow_path(jax_bls, monkeypatch):
+    """A block-shaped batch (one large aggregate sets K, the rest pad) packs
+    a point for each real key and each padding row's generator, never one
+    per infinity slot, and stages the very key arrays of the per-point slow
+    path (pack_g1 per slot, infinity in every unused one)."""
+    b = jax_bls
+    keys = [b.interop_keypair(i)[1] for i in range(12)]
+    sig = b.Signature.infinity()
+    lists = [keys[:1], keys[1:4], keys[:12], keys[4:6], keys[7:8]]
+    sets_ = [b.SignatureSet(signature=sig, signing_keys=ks, message=bytes([i]) * 32)
+             for i, ks in enumerate(lists)]
+    packed = []
+    real_pack = pack.pack_g1_batch
+
+    def spy(pts):
+        packed.append(len(pts))
+        return real_pack(pts)
+
+    monkeypatch.setattr(pack, "pack_g1_batch", spy)
+    pk_x, pk_y, pk_inf = japi.stage_sets(sets_, rng=japi._ONE_RNG)[:3]
+    S, K = pk_inf.shape
+    assert (S, K) == japi.batch_bucket(len(sets_), 12) == (8, 16)
+    assert packed == [sum(map(len, lists)) + S - len(sets_)]
+    gen = japi._pad_generator()
+    for i in range(S):
+        row = [pk.point for pk in lists[i]] if i < len(sets_) else [gen]
+        for j in range(K):
+            x, y, inf = pack.pack_g1(row[j]) if j < len(row) else pack.pack_g1(g1_infinity())
+            assert np.array_equal(pk_x[i, j], x) and np.array_equal(pk_y[i, j], y), (i, j)
+            assert pk_inf[i, j] == inf, (i, j)
+
+
+def test_dispatch_buffer_is_the_staged_arrays_as_int32_in_order(sets):
+    """_pack_staged writes each staged array once into the one buffer the
+    device unpacks: the int32 concatenation of their flattened values, bool
+    flags as 0/1, a non-contiguous array as its values."""
+    staged = japi.stage_sets(sets, rng=random.Random(3).getrandbits)
+    staged = (*staged[:3], staged[3][:, ::-1].copy()[:, ::-1], *staged[4:])  # strided sig_x
+    flat = japi._pack_staged(staged)
+    want = np.concatenate([np.ravel(np.asarray(a)).astype(np.int32) for a in staged])
+    assert flat.dtype == np.int32 and np.array_equal(flat, want)
+    assert flat.size == sum(int(np.prod(s)) for s, _ in japi._staged_specs(16, 4))
 
 
 def test_staging_floor_rounds_to_a_pow2_bucket(sets):
