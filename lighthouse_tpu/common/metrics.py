@@ -364,8 +364,9 @@ BLS_BATCH_PADDED_SIZE = REGISTRY.histogram(
     buckets=(4, 8, 16, 32, 64, 128, 256, 512),
 )
 # Every staged batch, bisection halves included: of its S rows, those that
-# carry a set and the padding; of its S x K key slots, those that hold a
-# real key and the padding (infinity points and the padding rows' keys).
+# carry a set and the padding; of its key slots (S x K, or N chunks x 32),
+# those that hold a real key and the padding (infinity points and the
+# padding rows' keys).
 BLS_STAGED_ROWS_TOTAL = REGISTRY.counter_vec(
     "lighthouse_tpu_bls_staged_rows_total",
     "Rows of staged verify batches: row=set carries a signature set, "
@@ -374,9 +375,18 @@ BLS_STAGED_ROWS_TOTAL = REGISTRY.counter_vec(
 )
 BLS_STAGED_KEY_SLOTS_TOTAL = REGISTRY.counter_vec(
     "lighthouse_tpu_bls_staged_key_slots_total",
-    "S x K key slots of staged verify batches: slot=key holds a real public "
-    "key, slot=pad is padding",
+    "Key slots of staged verify batches (S x K dense, N x 32 chunked): "
+    "slot=key holds a real public key, slot=pad is padding",
     ("slot",),
+)
+# Every staged batch: the layout its keys took. dense: (S, K) slots (K <= 32,
+# or stage_sets); chunked: the bucket's N(S, K) chunks of 32 slots (api.
+# key_chunks); chunked_over_budget: more chunks than that, a chain of its own.
+BLS_KEYS_LAYOUT_TOTAL = REGISTRY.counter_vec(
+    "lighthouse_tpu_bls_keys_layout_total",
+    "Staged verify batches by key layout: dense (S x K slots), chunked "
+    "(the bucket's chunks of 32 slots), chunked_over_budget (more chunks)",
+    ("layout",),
 )
 # Every staged batch, bisection halves included: its message rows, the rows
 # the device pairs (U = S/2 where the batch groups its sets by message, else

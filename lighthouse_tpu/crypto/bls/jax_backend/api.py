@@ -37,7 +37,7 @@ import threading
 import time
 from concurrent.futures import Future, ThreadPoolExecutor
 from dataclasses import dataclass
-from functools import lru_cache
+from functools import lru_cache, partial
 
 import numpy as np
 
@@ -218,6 +218,25 @@ def batch_bucket(n_sets: int, max_keys: int, s_floor: int = 4) -> tuple[int, int
     return _next_pow2(max(n_sets, s_floor)), _next_pow2(max_keys)
 
 
+# Key slots per chunk of the chunked key layout (key_chunks).
+KEY_CHUNK = 32
+
+
+def key_chunks(S: int, K: int) -> int | None:
+    """The chunks of KEY_CHUNK key slots the dispatch stages a bucket's
+    keys in, or None where K <= KEY_CHUNK and the keys keep the dense
+    (S, K) layout. Each set's keys fill ceil(k / KEY_CHUNK) chunks of their
+    own, so a bucket of ragged sets (a block: 1 to 512 keys) stages about
+    its real keys instead of S x K slots. N(S, K) = next_pow2(max(S K /
+    (4 KEY_CHUNK), S + K / KEY_CHUNK)): room for every set's first chunk
+    and one full K set, and a quarter of the dense slots (a block, 131 sets
+    in (256, 512), needs 783 of 1,024). A batch that needs more chunks
+    takes the next power of two (a chain of its own, built on first use)."""
+    if K <= KEY_CHUNK:
+        return None
+    return _next_pow2(max(S * K // (4 * KEY_CHUNK), S + K // KEY_CHUNK))
+
+
 def _tree_fold(F, pts, axis: int):
     """Sum projective points along `axis` with a pairwise halving tree:
     log2(n) batched additions instead of an n-step scan. Odd leftovers ride
@@ -250,16 +269,25 @@ def _tree_fold(F, pts, axis: int):
     return Proj(pts.x[0], pts.y[0], pts.z[0])
 
 
-def _keys_stage(pk_x, pk_y, pk_inf, r_bits):
+def _keys_stage(pk_x, pk_y, pk_inf, r_bits, chunk_set=None, spans: int = 1):
     """G1 side, per (S, K) bucket: aggregate each set's keys with a
-    log-depth pairwise tree over the K axis (the complete addition formulas
-    make P+P and P+inf safe, so a plain halving tree needs no masking),
-    then r_i * aggpk_i (G1 ladders, per-set 64-bit scalars). Returns
-    (r_pk, any aggregate at infinity)."""
+    log-depth pairwise tree over the slot axis (the complete addition
+    formulas make P+P and P+inf safe, so a plain halving tree needs no
+    masking), then r_i * aggpk_i (G1 ladders, per-set 64-bit scalars).
+    Returns (r_pk, any aggregate at infinity).
+
+    Dense layout: (S, K) slots, one row a set. Chunked layout (key_chunks):
+    (N, KEY_CHUNK) slots and `chunk_set` (N,), the set of each chunk
+    (sorted; S for an unused chunk), no set spanning more than `spans`
+    chunks: each chunk is folded, then a segmented scan of log2(spans)
+    steps sums each set's chunks (_segment_sums). The dense layout is the
+    chunked one with N = S and no scan."""
     from .curve import FP, from_affine, is_infinity, scalar_mul_bits
 
-    pks = from_affine(FP, pk_x, pk_y, pk_inf)  # (S, K) batch
+    pks = from_affine(FP, pk_x, pk_y, pk_inf)  # (S, K) or (N, KEY_CHUNK) batch
     agg = _tree_fold(FP, pks, axis=1)
+    if chunk_set is not None:
+        agg = _segment_sums(agg, chunk_set, r_bits.shape[0], spans.bit_length() - 1)
     return scalar_mul_bits(FP, agg, r_bits), jnp.any(is_infinity(FP, agg))
 
 
@@ -281,35 +309,44 @@ def _sigs_stage(sig_x, sig_y, sig_inf, r_bits):
     return _tree_fold(FP2, rsig, axis=0), jnp.all(sub_ok)
 
 
-def _group_sums(r_pk, group, U: int):
-    """G1 side of the grouped chain: the S points r_i * aggpk_i summed per
-    message group into U points, one per staged message row. The host
-    plan (_message_plan) orders the rows so each group is contiguous and
-    numbers the groups 0, 1, ... in row order.
+def _segment_sums(pts, seg, n: int, steps: int):
+    """G1 points summed per segment: lane i of `pts` belongs to segment
+    seg[i], each segment's lanes contiguous; returns the n sums of
+    segments 0..n-1 (a segment with no lane is infinity).
 
-    A segmented inclusive scan (Hillis-Steele) over the S lanes: log2(S)
+    A segmented inclusive scan (Hillis-Steele) over the lanes: `steps`
     steps, each one complete addition per lane, which adds the lane d = 2^k
-    back only when it holds the same group; the steps are one loop, so the
-    addition compiles once. A group's sum is then its last lane; a group
-    with no lane (an unused message row) is infinity, so its Miller pair
-    is 1."""
+    back only when it holds the same segment; the steps are one loop, so
+    the addition compiles once. After them each lane holds the sum of the
+    last 2^steps lanes of its segment, so a segment of at most that many
+    lanes has its sum in its last lane."""
     from .curve import FP, Proj, _sel, add, infinity
 
-    S = group.shape[0]
-    lane = jnp.arange(S, dtype=jnp.int32)
-    inf = infinity(FP, (S,))
+    L = seg.shape[0]
+    lane = jnp.arange(L, dtype=jnp.int32)
+    inf = infinity(FP, (L,))
 
     def step(k, p):
         d = jnp.left_shift(jnp.int32(1), k)
         back = Proj(*(jnp.roll(c, d, axis=0) for c in p))
-        same = (lane >= d) & (jnp.roll(group, d) == group)
+        same = (lane >= d) & (jnp.roll(seg, d) == seg)
         return add(FP, p, _sel(FP, same, back, inf))
 
-    p = lax.fori_loop(0, S.bit_length() - 1, step, r_pk)
-    rows = jnp.arange(U, dtype=jnp.int32)
-    last = jnp.max(jnp.where(group[None, :] == rows[:, None], lane[None, :], -1), axis=1)
+    p = lax.fori_loop(0, steps, step, pts)
+    rows = jnp.arange(n, dtype=jnp.int32)
+    last = jnp.max(jnp.where(seg[None, :] == rows[:, None], lane[None, :], -1), axis=1)
     sums = Proj(*(jnp.take(c, last, axis=0, mode="clip") for c in p))
-    return _sel(FP, last >= 0, sums, infinity(FP, (U,)))
+    return _sel(FP, last >= 0, sums, infinity(FP, (n,)))
+
+
+def _group_sums(r_pk, group, U: int):
+    """G1 side of the grouped chain: the S points r_i * aggpk_i summed per
+    message group into U points, one per staged message row. The host
+    plan (_message_plan) orders the rows so each group is contiguous and
+    numbers the groups 0, 1, ... in row order. A scan of log2(S) steps
+    (_segment_sums), as a group may span every row; a group with no row
+    (an unused message row) is infinity, so its Miller pair is 1."""
+    return _segment_sums(r_pk, group, U, group.shape[0].bit_length() - 1)
 
 
 def _pairs_stage(r_pk, H, sig_acc):
@@ -385,20 +422,26 @@ def verify_pipeline_local(pk_x, pk_y, pk_inf, sig_x, sig_y, sig_inf, u, r_bits):
     return partial, sub_ok & ~agg_inf
 
 
-def _staged_specs(S: int, K: int, U: int | None = None):
-    """(shape, is_bool) of each staged array, in stage_sets order; with U
-    message rows (the grouped chain), u has U rows and each set's group
-    index follows."""
+def _staged_specs(S: int, K: int, U: int | None = None, N: int | None = None):
+    """(shape, is_bool) of each staged array of the dispatch's layout, in
+    stage_sets order; with N key chunks (the chunked key layout, by default
+    the bucket's key_chunks(S, K)) the keys take (N, KEY_CHUNK) slots and
+    each chunk's set index follows r_bits; with U message rows (the grouped
+    chain), u has U rows and each set's group index comes last."""
+    N = N or key_chunks(S, K)
+    slots = (S, K) if N is None else (N, KEY_CHUNK)
     specs = [
-        ((S, K, 32), False),  # pk_x
-        ((S, K, 32), False),  # pk_y
-        ((S, K), True),  # pk_inf
+        ((*slots, 32), False),  # pk_x
+        ((*slots, 32), False),  # pk_y
+        (slots, True),  # pk_inf
         ((S, 2, 32), False),  # sig_x
         ((S, 2, 32), False),  # sig_y
         ((S,), True),  # sig_inf
         ((S if U is None else U, 2, 2, 32), False),  # u
         ((S, 64), False),  # r_bits
     ]
+    if N is not None:
+        specs.append(((N,), False))  # chunk_set
     if U is not None:
         specs.append(((S,), False))  # group
     return specs
@@ -418,9 +461,9 @@ def _pack_staged(staged) -> np.ndarray:
     return flat
 
 
-def _unpack_staged(flat, S: int, K: int, U: int | None = None):
+def _unpack_staged(flat, S: int, K: int, U: int | None = None, N: int | None = None):
     out, off = [], 0
-    for shape, is_bool in _staged_specs(S, K, U):
+    for shape, is_bool in _staged_specs(S, K, U, N):
         n = int(np.prod(shape))
         a = flat[off : off + n].reshape(shape)
         out.append(a.astype(bool) if is_bool else a)
@@ -438,6 +481,11 @@ def _unpack_staged(flat, S: int, K: int, U: int | None = None):
 # program; the stages compile in seconds to ~2 min each, concurrently, and the
 # final exponentiation (shape-free) and the S-only stages are shared by
 # every bucket that needs them. Intermediates stay on the device.
+#
+# Where K > KEY_CHUNK, the unpack and keys programs take the chunked key
+# layout (key_chunks): N chunks of KEY_CHUNK slots, a set's keys in chunks
+# of its own, in place of S x K slots; the chain's other programs never see
+# the keys. The dispatch picks the layout from K alone.
 #
 # Hash-to-G2 is a stage of its own, with a barrier inside (h2c.map_to_g2):
 # fused whole, the TPU compiler got the map's y wrong (my chip runs, PR 21).
@@ -459,14 +507,18 @@ _GROUPED_CHAIN = ("unpack", "keys", "sigs", "h2c", "group", "pairs", "final")
 GROUP_MIN_S = 8
 
 
-def _stage_key(name: str, S: int, K: int, U: int | None = None) -> tuple:
+def _stage_key(name: str, S: int, K: int, U: int | None = None,
+               N: int | None = None) -> tuple:
     """The shapes a stage program depends on: (S, K), S alone, or none; in
     the grouped chain (U message rows) the unpack program also U, group
-    (S, U), and pairs U alone."""
+    (S, U), and pairs U alone; in the chunked key layout (N chunks) the
+    unpack and keys programs also N."""
     if name == "unpack":
+        if N is not None:
+            return (name, S, K, U, N)
         return (name, S, K) if U is None else (name, S, K, U)
     if name == "keys":
-        return (name, S, K)
+        return (name, S, K) if N is None else (name, S, K, N)
     if name == "final":
         return (name,)
     if name == "group":
@@ -476,11 +528,13 @@ def _stage_key(name: str, S: int, K: int, U: int | None = None) -> tuple:
     return (name, S)
 
 
-def _chain_keys(S: int, K: int, U: int | None = None) -> list[tuple]:
+def _chain_keys(S: int, K: int, U: int | None = None, N: int | None = None) -> list[tuple]:
     """The stage keys of bucket (S, K)'s full chain, or of its grouped
-    chain over U message rows."""
+    chain over U message rows; its keys in the bucket's key_chunks(S, K)
+    chunks, or in N where a batch needs more."""
     names = _VERIFY_CHAIN if U is None else _GROUPED_CHAIN
-    return [_stage_key(name, S, K, U) for name in names]
+    N = N or key_chunks(S, K)
+    return [_stage_key(name, S, K, U, N) for name in names]
 
 
 def _bucket_chains(S: int, K: int) -> list[tuple]:
@@ -519,12 +573,18 @@ def verify_stage(key: tuple):
         return _group_stage, (g1, i32(S), Proj(*[i32(S, 2, 32)] * 3))
     K = shape[1]
     if name == "keys":
-        return _keys_stage, (i32(S, K, 32), i32(S, K, 32), b(S, K), i32(S, 64))
+        if len(shape) == 2:
+            return _keys_stage, (i32(S, K, 32), i32(S, K, 32), b(S, K), i32(S, 64))
+        N, C = shape[2], KEY_CHUNK
+        keys = partial(_keys_stage, spans=K // C)
+        keys.__name__ = _keys_stage.__name__  # the jit name traces and profiles show
+        return keys, (i32(N, C, 32), i32(N, C, 32), b(N, C), i32(S, 64), i32(N))
     U = shape[2] if len(shape) > 2 else None
-    n = sum(int(np.prod(sh)) for sh, _ in _staged_specs(S, K, U))
+    N = shape[3] if len(shape) > 3 else None
+    n = sum(int(np.prod(sh)) for sh, _ in _staged_specs(S, K, U, N))
 
     def _unpack_stage(flat):  # named: traces and profiles show the jit name
-        return _unpack_staged(flat, S, K, U)
+        return _unpack_staged(flat, S, K, U, N)
 
     return _unpack_stage, (i32(n),)
 
@@ -570,7 +630,7 @@ def _fail_kernel(fut: Future, key: tuple, exc: BaseException) -> None:
 
 def _stage_label(key: tuple) -> str:
     """A stage key as the profiler annotation of its build: keys-128-4."""
-    return "-".join(map(str, key))
+    return "-".join(str(p) for p in key if p is not None)
 
 
 # The persistent-cache result of the compile running in this thread: JAX
@@ -618,6 +678,13 @@ def compile_verify_kernels(buckets) -> dict[tuple[int, int], float]:
     compile or cache load a `bls_compile` span in a pool thread; the
     profiler annotation of each carries the stage key. Each build counts
     its persistent-cache result (lighthouse_tpu_bls_compile_cache_total)."""
+    ready = _compile_chains([chain for S, K in buckets for chain in _bucket_chains(S, K)])
+    return {(S, K): max(ready[chain] for chain in _bucket_chains(S, K)) for S, K in buckets}
+
+
+def _compile_chains(chains) -> dict[tuple, float]:
+    """compile_verify_kernels' builder, by chain (_chain_keys' arguments):
+    the seconds until each chain's last stage was ready."""
     from ....common.tracing import span
 
     t0 = time.perf_counter()
@@ -627,8 +694,7 @@ def compile_verify_kernels(buckets) -> dict[tuple[int, int], float]:
     def note(key):
         return lambda _fut: done_at.setdefault(key, time.perf_counter() - t0)
 
-    chains = [chain for S, K in buckets for chain in _bucket_chains(S, K)]
-    chains.sort(key=len)  # stable: the full chains (S, K) first
+    chains = sorted(chains, key=len)  # stable: the full chains (S, K) first
     with ThreadPoolExecutor(max_workers=COMPILE_WORKERS) as pool:
         for chain in chains:
             for key in _chain_keys(*chain):
@@ -646,11 +712,11 @@ def compile_verify_kernels(buckets) -> dict[tuple[int, int], float]:
                     pool.submit(_build_kernel, fut, key, lowered)
                 fut.add_done_callback(note(key))
     ready = {}
-    for S, K in buckets:
-        keys = [key for chain in _bucket_chains(S, K) for key in _chain_keys(*chain)]
+    for chain in chains:
+        keys = _chain_keys(*chain)
         for key in keys:
             futures[key].result()
-        ready[(S, K)] = max(done_at[key] for key in keys)
+        ready[chain] = max(done_at[key] for key in keys)
     return ready
 
 
@@ -661,32 +727,34 @@ def compiled_stage(key: tuple):
     return _kernels[key].result()
 
 
-def _verify_kernel(S: int, K: int, U: int | None = None):
+def _verify_kernel(S: int, K: int, U: int | None = None, N: int | None = None):
     """The batch-verify chain for bucket (S, K), or its grouped chain over
-    U message rows: flat staged buffer (see _pack_staged) -> device bool,
-    dispatched stage by stage without a host sync."""
-    chain = (S, K) if U is None else (S, K, U)
+    U message rows, its keys in N chunks where a batch needs more than the
+    bucket's key_chunks(S, K): flat staged buffer (see _pack_staged) ->
+    device bool, dispatched stage by stage without a host sync. A bucket's
+    own chains are built as compile_verify_kernels builds them; a chain of
+    more chunks alone, on first use."""
+    chain = (S, K, U, N) if N is not None else (S, K) if U is None else (S, K, U)
     run = _chains.get(chain)
     if run is not None:
         return run
-    compile_verify_kernels([(S, K)])
-    stages = [_kernels[key].result() for key in _chain_keys(*chain)]
-    if U is None:
-        unpack, keys, sigs, h2c, pairs, final = stages
-
-        def run(flat):
-            pk_x, pk_y, pk_inf, sig_x, sig_y, sig_inf, u, r_bits = unpack(flat)
-            r_pk, agg_inf = keys(pk_x, pk_y, pk_inf, r_bits)
-            sig_acc, sub_ok = sigs(sig_x, sig_y, sig_inf, r_bits)
-            return final(pairs(r_pk, h2c(u), sig_acc), sub_ok, agg_inf)
+    if N is None:
+        compile_verify_kernels([(S, K)])
     else:
-        unpack, keys, sigs, h2c, group, pairs, final = stages
+        _compile_chains([chain])
+    stages = [_kernels[key].result() for key in _chain_keys(*chain)]
+    unpack, keys, sigs, h2c, *group, pairs, final = stages
+    chunked = key_chunks(S, K) is not None  # chunk_set follows r_bits
 
-        def run(flat):
-            pk_x, pk_y, pk_inf, sig_x, sig_y, sig_inf, u, r_bits, grp = unpack(flat)
-            r_pk, agg_inf = keys(pk_x, pk_y, pk_inf, r_bits)
-            sig_acc, sub_ok = sigs(sig_x, sig_y, sig_inf, r_bits)
-            return final(pairs(*group(r_pk, grp, h2c(u)), sig_acc), sub_ok, agg_inf)
+    def run(flat):
+        staged = unpack(flat)
+        r_bits = staged[7]
+        r_pk, agg_inf = keys(*staged[:3], r_bits, *staged[8 : 8 + chunked])
+        sig_acc, sub_ok = sigs(*staged[3:6], r_bits)
+        rows = (r_pk, h2c(staged[6]))
+        if group:
+            rows = group[0](r_pk, staged[-1], rows[1])
+        return final(pairs(*rows, sig_acc), sub_ok, agg_inf)
 
     _chains[chain] = run
     return run
@@ -756,10 +824,11 @@ def _message_plan(messages: list[bytes], S: int):
 
 
 def stage_sets(sets: list[SignatureSet], rng=None, s_floor: int = 4):
-    """Host staging for the device kernels: pad the batch to the S bucket
-    (pow2, >= s_floor) with (generator-keyed, r=0) no-op sets and each key
-    list to the K bucket with infinity points (additive identity). Returns
-    (pk_x, pk_y, pk_inf, sig_x, sig_y, sig_inf, u, r_bits) numpy arrays.
+    """Host staging for the device kernels, in the dense key layout that
+    verify_pipeline_local takes: pad the batch to the S bucket (pow2, >=
+    s_floor) with (generator-keyed, r=0) no-op sets and each key list to
+    the K bucket with infinity points (additive identity). Returns (pk_x,
+    pk_y, pk_inf, sig_x, sig_y, sig_inf, u, r_bits) numpy arrays.
 
     This is the staging FAST path: point limb rows are gathered from the
     per-point cache (pack.py) with misses bulk-converted, hash-to-field
@@ -767,25 +836,31 @@ def stage_sets(sets: list[SignatureSet], rng=None, s_floor: int = 4):
     RLC scalars are drawn/bit-expanded in one batched call. Output is
     byte-identical to the per-element slow path (asserted in
     tests/test_staging.py); the whole call is timed as the `bls_stage`
-    span / lighthouse_tpu_bls_stage_seconds, and its S rows, S x K key
-    slots and message rows are counted, real and padding
+    span / lighthouse_tpu_bls_stage_seconds, and its S rows, key slots,
+    key layout and message rows are counted, real and padding
     (lighthouse_tpu_bls_staged_rows_total, ..._staged_key_slots_total,
-    ..._message_rows_total)."""
-    return _stage(sets, rng, s_floor, plan=False)[0]
+    ..._keys_layout_total, ..._message_rows_total)."""
+    return _stage(sets, rng, s_floor, dispatch=False)[0]
 
 
 def stage_sets_grouped(sets: list[SignatureSet], rng=None, s_floor: int = 4):
     """The dispatch's staging: stage_sets, with the message plan
-    (_message_plan) made inside the same `bls_stage` span. Returns
-    (staged, group): stage_sets' arrays and None where the batch takes the
-    full chain; else those arrays in the plan's order, u holding the U
-    message rows, and the (S,) int32 group index of each row."""
-    return _stage(sets, rng, s_floor, plan=True)
+    (_message_plan) made inside the same `bls_stage` span, and the keys in
+    the chunked layout where K > KEY_CHUNK (key_chunks). Returns (staged,
+    group): staged is stage_sets' arrays in the plan's row order, its keys
+    (N, KEY_CHUNK) slots followed by the (N,) chunk_set after r_bits where
+    chunked, u the U message rows where grouped; group the (S,) int32 group
+    index of each row, or None where the batch takes the full chain."""
+    return _stage(sets, rng, s_floor, dispatch=True)[:2]
 
 
-def _stage(sets, rng, s_floor: int, plan: bool):
+def _stage(sets, rng, s_floor: int, dispatch: bool):
+    """stage_sets (dense, no message plan) or the dispatch's staging
+    (stage_sets_grouped). Returns (staged, group, chain): chain the
+    arguments of the batch's _verify_kernel."""
     from ....common.metrics import (
         BLS_BATCH_PADDED_SIZE,
+        BLS_KEYS_LAYOUT_TOTAL,
         BLS_MESSAGE_ROWS_TOTAL,
         BLS_STAGE_SECONDS,
         BLS_STAGED_KEY_SLOTS_TOTAL,
@@ -797,36 +872,48 @@ def _stage(sets, rng, s_floor: int, plan: bool):
 
     S, K = batch_bucket(len(sets), max(len(s.signing_keys) for s in sets), s_floor)
     BLS_BATCH_PADDED_SIZE.observe(S)
-    n_keys = sum(len(s.signing_keys) for s in sets)
-    BLS_STAGED_ROWS_TOTAL.labels(row="set").inc(len(sets))
-    BLS_STAGED_ROWS_TOTAL.labels(row="pad").inc(S - len(sets))
-    BLS_STAGED_KEY_SLOTS_TOTAL.labels(slot="key").inc(n_keys)
-    BLS_STAGED_KEY_SLOTS_TOTAL.labels(slot="pad").inc(S * K - n_keys)
+    n = len(sets)
+    msgs = [s.message for s in sets]
+    BLS_STAGED_ROWS_TOTAL.labels(row="set").inc(n)
+    BLS_STAGED_ROWS_TOTAL.labels(row="pad").inc(S - n)
 
     with BLS_STAGE_SECONDS.time(), span("bls_stage"):
-        n = len(sets)
+        grouping = _message_plan(msgs, S) if dispatch else None
+        # the plan orders the real rows (its padding rows stay last), so a
+        # chunked set's chunks follow the grouped row order
+        order = None if grouping is None else grouping[0][:n]
+        rows = sets if order is None else [sets[i] for i in order]
         # Only the real keys and the padding rows' generators are packed;
         # the other key slots are infinity (zero limbs, flag set) and are
-        # never a Point: in a block's (256, 512) bucket 86% of the 131,072
-        # slots are such padding.
+        # never a Point.
         key_pts: list[Point] = []
-        sig_pts: list[Point] = []
-        msgs: list[bytes] = []
         n_row = np.ones(S, dtype=np.int64)  # keys in each row
-        for i, s in enumerate(sets):
+        for i, s in enumerate(rows):
             key_pts.extend(pk.point for pk in s.signing_keys)
             n_row[i] = len(s.signing_keys)
-            sig_pts.append(s.signature.point)
-            msgs.append(s.message)
+        sig_pts = [s.signature.point for s in rows]
         if S > n:
             key_pts.extend([_pad_generator()] * (S - n))
             sig_pts.extend([g2_infinity()] * (S - n))
-            msgs.extend([b""] * (S - n))
             # r stays 0: the padded set contributes the identity everywhere.
-        # a row's keys fill its first slots: key g of row i sits at slot
-        # i*K + (g - the row's first key)
-        slot = np.arange(len(key_pts)) + np.repeat(
-            np.arange(S) * K - (np.cumsum(n_row) - n_row), n_row)
+        # Where each row's keys start: row i at slot i*K (dense), or at its
+        # first chunk's first slot (chunked: ceil(k / KEY_CHUNK) chunks a
+        # row, one for a padding row, the unused chunks last, set index S).
+        N = key_chunks(S, K) if dispatch else None
+        layout = "dense" if N is None else "chunked"
+        if N is None:
+            slots, row_slot = (S, K), np.arange(S) * K
+        else:
+            n_chunk = -(-n_row // KEY_CHUNK)
+            used = int(n_chunk.sum())
+            if used > N:
+                N, layout = _next_pow2(used), "chunked_over_budget"
+            slots = (N, KEY_CHUNK)
+            row_slot = (np.cumsum(n_chunk) - n_chunk) * KEY_CHUNK
+            chunk_set = np.full(N, S, dtype=np.int32)
+            chunk_set[:used] = np.repeat(np.arange(S, dtype=np.int32), n_chunk)
+        # key g of row i sits at the row's first slot + (g - its first key)
+        slot = np.arange(len(key_pts)) + np.repeat(row_slot - (np.cumsum(n_row) - n_row), n_row)
 
         r_rows = np.zeros((S, 64), dtype=np.int32)
         if n:
@@ -843,32 +930,37 @@ def _stage(sets, rng, s_floor: int, plan: bool):
                     while r == 0:
                         r = rng(RAND_BITS)
                     rs.append(r)
-            r_rows[:n] = _scalar_bits_batch(rs)
+            bits = _scalar_bits_batch(rs)
+            r_rows[:n] = bits if order is None else bits[order]
 
         with span("bls_pack"):
             x, y, inf = pack_g1_batch(key_pts)
-            pk_x = np.zeros((S * K, x.shape[-1]), dtype=np.int32)
+            n_slots = slots[0] * slots[1]
+            pk_x = np.zeros((n_slots, x.shape[-1]), dtype=np.int32)
             pk_y = np.zeros(pk_x.shape, dtype=np.int32)
-            pk_inf = np.ones(S * K, dtype=bool)
+            pk_inf = np.ones(n_slots, dtype=bool)
             pk_x[slot], pk_y[slot], pk_inf[slot] = x, y, inf
-            pk_x = pk_x.reshape(S, K, -1)
-            pk_y = pk_y.reshape(S, K, -1)
-            pk_inf = pk_inf.reshape(S, K)
+            pk_x = pk_x.reshape(*slots, -1)
+            pk_y = pk_y.reshape(*slots, -1)
+            pk_inf = pk_inf.reshape(slots)
             sig_x, sig_y, sig_inf = pack_g2_batch(sig_pts)
-        grouping = _message_plan(msgs[:n], S) if plan else None
         with span("bls_h2c_host"):
-            u = h2c.hash_to_field_limbs(msgs if grouping is None else grouping[2])
-        group = None
-        if grouping is not None:
-            order, group, _ = grouping
-            pk_x, pk_y, pk_inf, sig_x, sig_y, sig_inf, r_rows = (
-                a[order] for a in (pk_x, pk_y, pk_inf, sig_x, sig_y, sig_inf, r_rows)
-            )
+            u = h2c.hash_to_field_limbs(
+                msgs + [b""] * (S - n) if grouping is None else grouping[2])
     staged = (pk_x, pk_y, pk_inf, sig_x, sig_y, sig_inf, u, r_rows)
-    distinct = len(set(msgs[:n]))
+    if layout != "dense":
+        staged += (chunk_set,)
+    n_keys = len(key_pts) - (S - n)
+    BLS_KEYS_LAYOUT_TOTAL.labels(layout=layout).inc()
+    BLS_STAGED_KEY_SLOTS_TOTAL.labels(slot="key").inc(n_keys)
+    BLS_STAGED_KEY_SLOTS_TOTAL.labels(slot="pad").inc(n_slots - n_keys)
+    distinct = len(set(msgs))
     BLS_MESSAGE_ROWS_TOTAL.labels(row="distinct").inc(distinct)
     BLS_MESSAGE_ROWS_TOTAL.labels(row="pad").inc(len(u) - distinct)
-    return staged, group
+    group = None if grouping is None else grouping[1]
+    U = None if grouping is None else len(u)
+    chain = (S, K, U, N) if layout == "chunked_over_budget" else (S, K) if U is None else (S, K, U)
+    return staged, group, chain
 
 
 def drop_staging_caches(sets) -> None:
@@ -951,11 +1043,9 @@ def verify_signature_sets_async(
 
     if not _structurally_valid(sets):
         return _INVALID
-    staged, group = stage_sets_grouped(sets, rng=rng, s_floor=s_floor)
-    if group is None:
-        kernel = _verify_kernel(*staged[2].shape)
-    else:
-        kernel = _verify_kernel(*staged[2].shape, len(staged[6]))
+    staged, group, chain = _stage(sets, rng, s_floor, dispatch=True)
+    kernel = _verify_kernel(*chain)
+    if group is not None:
         staged = (*staged, group)
     # the host side of a dispatch's fixed cost: one buffer packed, copied
     # to the device, and the chain's programs launched

@@ -420,6 +420,101 @@ def test_staging_floor_rounds_to_a_pow2_bucket(sets):
     assert staged[2].shape == japi.batch_bucket(len(sets), 3, 100) == (128, 4)
 
 
+# -- the chunked key layout (K > KEY_CHUNK) --------------------------------------
+
+
+@pytest.fixture(scope="module")
+def keys64(jax_bls):
+    return [jax_bls.interop_keypair(i)[1] for i in range(64)]
+
+
+def _key_sets(b, keys, lists, msgs=None):
+    sig = b.Signature.infinity()
+    msgs = msgs or [bytes([i]) * 32 for i in range(len(lists))]
+    return [b.SignatureSet(signature=sig, signing_keys=[keys[j] for j in ks], message=m)
+            for ks, m in zip(lists, msgs)]
+
+
+def _assert_chunked_rows(staged, rows):
+    """Row i's keys fill the slots of the chunks whose set index is i, in
+    order, each as pack_g1 packs it, infinity after them; the unused
+    chunks hold infinity and set index S."""
+    pk_x, pk_y, pk_inf, chunk_set = staged[0], staged[1], staged[2], staged[8]
+    C, S = japi.KEY_CHUNK, len(staged[7])
+    assert pk_x.shape == (len(chunk_set), C, 32) and pk_inf.shape == (len(chunk_set), C)
+    assert chunk_set.dtype == np.int32 and np.all(np.diff(chunk_set) >= 0)
+    for i, pts in enumerate(rows):
+        chunks = np.flatnonzero(chunk_set == i)
+        assert len(chunks) == -(-len(pts) // C), i
+        assert np.array_equal(chunks, np.arange(chunks[0], chunks[0] + len(chunks)))
+        slots = len(chunks) * C
+        xs, ys, infs = (a[chunks].reshape(slots, *a.shape[2:]) for a in (pk_x, pk_y, pk_inf))
+        for j in range(slots):
+            x, y, inf = pack.pack_g1(pts[j] if j < len(pts) else g1_infinity())
+            assert np.array_equal(xs[j], x) and np.array_equal(ys[j], y) and infs[j] == inf
+    unused = chunk_set == S
+    assert pk_inf[unused].all() and not pk_x[unused].any() and not pk_y[unused].any()
+
+
+def test_chunked_staging_puts_each_key_in_its_sets_chunks_in_order(jax_bls, keys64):
+    """A ragged batch with K > 32: (8, 64) in the bucket's 16 chunks of 32
+    slots, the padding rows' generator in a chunk of their own."""
+    lists = [[0], list(range(33)), list(range(64)), list(range(5, 17)), list(range(31))]
+    sets_ = _key_sets(jax_bls, keys64, lists)
+    staged, group = japi.stage_sets_grouped(sets_, rng=random.Random(5).getrandbits)
+    assert group is None and japi.key_chunks(8, 64) == 16 == len(staged[8])
+    gen = japi._pad_generator()
+    _assert_chunked_rows(staged, [[s.point for s in st.signing_keys] for st in sets_]
+                         + [[gen]] * 3)
+    assert staged[8].tolist() == [0, 1, 1, 2, 2, 3, 4, 5, 6, 7] + [8] * 6
+    # every array but the keys' is stage_sets' own
+    dense = japi.stage_sets(sets_, rng=random.Random(5).getrandbits)
+    for k in range(3, 8):
+        assert np.array_equal(staged[k], dense[k]), k
+
+
+def test_chunked_staging_follows_the_message_plan_order(jax_bls, keys64):
+    """In a grouped batch the plan orders the sets first, and their chunks
+    follow that order: the chunk->set index stays sorted."""
+    lists = [list(range(40)), [1], list(range(64)), [2, 3], list(range(33))]
+    msgs = [b"a" * 32, b"b" * 32, b"a" * 32, b"b" * 32, b"c" * 32]
+    sets_ = _key_sets(jax_bls, keys64, lists, msgs)
+    seeded = lambda: random.Random(9).getrandbits  # noqa: E731
+    staged, group = japi.stage_sets_grouped(sets_, rng=seeded())
+    order, plan_group, _ = japi._message_plan(msgs, 8)
+    assert np.array_equal(group, plan_group) and order[:5].tolist() == [0, 2, 1, 3, 4]
+    gen = japi._pad_generator()
+    _assert_chunked_rows(staged, [[pk.point for pk in sets_[i].signing_keys] for i in order[:5]]
+                         + [[gen]] * 3)
+    dense = japi.stage_sets(sets_, rng=seeded())
+    for k in (3, 4, 5, 7):  # sig_x, sig_y, sig_inf, r_bits: each set's own row
+        assert np.array_equal(staged[k], dense[k][order]), k
+
+
+@pytest.mark.parametrize(
+    "batch,digest",
+    [
+        ("sets", "03a188449a2939d6b2b3074e29751e1042dfccd1a41dca7c304996665ec0cdcf"),
+        ("k32", "f92831273664b5e25741174253c4fd0e4e583b0f682bf12037d45ecdb1c6b0c8"),
+    ],
+    ids=["grouped-16x4", "full-8x32"],
+)
+def test_a_batch_of_at_most_32_keys_stages_its_dense_buffer_unchanged(
+        jax_bls, sets, keys64, batch, digest):
+    """Where K <= KEY_CHUNK the dispatch stages the dense (S, K) layout,
+    byte for byte the buffer it staged before the chunked layout existed
+    (its SHA-256 then): this module's batch (grouped) and a 32-key one."""
+    import hashlib
+
+    if batch == "k32":
+        sets = _key_sets(jax_bls, keys64, [list(range(k)) for k in (32, 1, 5, 17, 2)])
+    staged, group = japi.stage_sets_grouped(sets, rng=random.Random(42).getrandbits)
+    assert japi.key_chunks(*staged[2].shape) is None and len(staged) == 8
+    if group is not None:
+        staged = (*staged, group)
+    assert hashlib.sha256(japi._pack_staged(staged).tobytes()).hexdigest() == digest
+
+
 # -- the coalescer's staging stage ---------------------------------------------
 
 
