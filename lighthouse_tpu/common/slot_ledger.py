@@ -280,8 +280,7 @@ class SlotLedger:
 
     def stage_report(self) -> dict[str, dict]:
         """{stage: {count, total_s, mean_s}} aggregated over closed slots —
-        the same shape Tracer.stage_report() emits, so one table printer
-        (scripts/profile_stages.py print_stage_table) renders both."""
+        the same shape Tracer.stage_report() emits."""
         totals: dict[str, float] = {}
         counts: dict[str, int] = {}
         for rec in self.records():

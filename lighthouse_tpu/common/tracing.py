@@ -5,9 +5,8 @@ Every pipeline stage (block import, processor dispatch, BLS device funnel)
 runs under `span("stage_name")`. A span times itself, nests under whatever
 span is open on ITS thread, and on completion feeds
 `lighthouse_tpu_stage_seconds{stage=...}` and the tracer's cumulative self
-times (duration minus children), so the Prometheus scrape, the slot ledger
-(common/slot_ledger.py) and scripts/profile_stages.py all report from the
-same measurements.
+times (duration minus children), so the Prometheus scrape and the slot
+ledger (common/slot_ledger.py) report from the same measurements.
 
 When jax is loaded, a span also opens a profiler annotation of its name
 (`jax.profiler.TraceAnnotation`). Under a running profiler trace that is an
@@ -94,7 +93,7 @@ class Tracer:
 
     def stage_report(self) -> dict[str, dict]:
         """{stage: {count, total_s, mean_s}} from the stage histogram — the
-        table profile_stages.py and bench rounds print."""
+        shape SlotLedger.stage_report() emits too."""
         out = {}
         for (stage,), child in sorted(self._stage_histogram.children().items()):
             n = child.count

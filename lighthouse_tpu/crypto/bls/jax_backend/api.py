@@ -71,14 +71,10 @@ def _coalescer():
 
 
 def device_fingerprint(refresh_gauge: bool = True) -> dict:
-    """Backend provenance (ISSUE 17): the fingerprint bench.py stamps into
-    every BENCH_*.json and /metrics exports as an info-style gauge (value 1,
-    identity in the labels). Host-side only — never called from (or
-    reachable by) the jitted kernels, so trace purity is untouched.
-
-    The r05 bench wedge silently fell back to CPU and the run was recorded
-    as device data; with the platform/device identity stamped into the
-    artifact, that mistake cannot repeat."""
+    """Backend provenance: the platform, device and compile-cache state,
+    which /metrics exports as an info-style gauge (value 1, identity in the
+    labels). Host-side only — never called from (or reachable by) the
+    jitted kernels, so trace purity is untouched."""
     from ..batch_verifier import DEFAULT_MAX_WAIT, DEFAULT_S_BUCKET
     from ....common.metrics import DEVICE_PROVENANCE_INFO
 
@@ -422,31 +418,6 @@ def verify_pipeline_local(pk_x, pk_y, pk_inf, sig_x, sig_y, sig_inf, u, r_bits):
     return partial, sub_ok & ~agg_inf
 
 
-def _staged_specs(S: int, K: int, U: int | None = None, N: int | None = None):
-    """(shape, is_bool) of each staged array of the dispatch's layout, in
-    stage_sets order; with N key chunks (the chunked key layout, by default
-    the bucket's key_chunks(S, K)) the keys take (N, KEY_CHUNK) slots and
-    each chunk's set index follows r_bits; with U message rows (the grouped
-    chain), u has U rows and each set's group index comes last."""
-    N = N or key_chunks(S, K)
-    slots = (S, K) if N is None else (N, KEY_CHUNK)
-    specs = [
-        ((*slots, 32), False),  # pk_x
-        ((*slots, 32), False),  # pk_y
-        (slots, True),  # pk_inf
-        ((S, 2, 32), False),  # sig_x
-        ((S, 2, 32), False),  # sig_y
-        ((S,), True),  # sig_inf
-        ((S if U is None else U, 2, 2, 32), False),  # u
-        ((S, 64), False),  # r_bits
-    ]
-    if N is not None:
-        specs.append(((N,), False))  # chunk_set
-    if U is not None:
-        specs.append(((S,), False))  # group
-    return specs
-
-
 def _pack_staged(staged) -> np.ndarray:
     """Write the staged arrays into ONE int32 buffer: a single host->device
     transfer instead of eight, paying the per-transfer fixed cost once.
@@ -461,16 +432,16 @@ def _pack_staged(staged) -> np.ndarray:
     return flat
 
 
-def _unpack_staged(flat, S: int, K: int, U: int | None = None, N: int | None = None):
-    out, off = [], 0
-    for shape, is_bool in _staged_specs(S, K, U, N):
+def _unpack_staged(flat, chain: "Chain"):
+    staged, off = {}, 0
+    for name, shape, is_bool in chain.staged_fields():
         n = int(np.prod(shape))
         a = flat[off : off + n].reshape(shape)
-        out.append(a.astype(bool) if is_bool else a)
+        staged[name] = a.astype(bool) if is_bool else a
         off += n
-    if U is not None:  # the grouped chain hashes its U rows at S-row width
-        out[6] = jnp.concatenate([out[6]] * (S // U))
-    return tuple(out)
+    if chain.U is not None:  # the grouped chain hashes its U rows at S-row width
+        staged["u"] = jnp.concatenate([staged["u"]] * (chain.S // chain.U))
+    return tuple(staged.values())
 
 
 # -- the single-chip program chain ---------------------------------------------
@@ -482,10 +453,12 @@ def _unpack_staged(flat, S: int, K: int, U: int | None = None, N: int | None = N
 # final exponentiation (shape-free) and the S-only stages are shared by
 # every bucket that needs them. Intermediates stay on the device.
 #
-# Where K > KEY_CHUNK, the unpack and keys programs take the chunked key
-# layout (key_chunks): N chunks of KEY_CHUNK slots, a set's keys in chunks
-# of its own, in place of S x K slots; the chain's other programs never see
-# the keys. The dispatch picks the layout from K alone.
+# A Chain is the one place a batch's shapes and programs are decided: bucket
+# (S, K), U message rows where it groups sets by message, N chunks of
+# KEY_CHUNK key slots where K > KEY_CHUNK (key_chunks: a set's keys in chunks
+# of its own, in place of S x K slots). It gives each stage program's key,
+# only the shapes that program depends on (so chains share programs), and
+# the staged buffer's fields by name. _dispatch_chain chooses a batch's chain.
 #
 # Hash-to-G2 is a stage of its own, with a barrier inside (h2c.map_to_g2):
 # fused whole, the TPU compiler got the map's y wrong (my chip runs, PR 21).
@@ -507,86 +480,106 @@ _GROUPED_CHAIN = ("unpack", "keys", "sigs", "h2c", "group", "pairs", "final")
 GROUP_MIN_S = 8
 
 
-def _stage_key(name: str, S: int, K: int, U: int | None = None,
-               N: int | None = None) -> tuple:
-    """The shapes a stage program depends on: (S, K), S alone, or none; in
-    the grouped chain (U message rows) the unpack program also U, group
-    (S, U), and pairs U alone; in the chunked key layout (N chunks) the
-    unpack and keys programs also N."""
-    if name == "unpack":
-        if N is not None:
-            return (name, S, K, U, N)
-        return (name, S, K) if U is None else (name, S, K, U)
-    if name == "keys":
-        return (name, S, K) if N is None else (name, S, K, N)
-    if name == "final":
-        return (name,)
-    if name == "group":
-        return (name, S, U)
-    if name == "pairs" and U is not None:
-        return (name, U)
-    return (name, S)
+@dataclass(frozen=True)
+class Chain:
+    """The chain of stage programs a batch runs: bucket (S, K), U message
+    rows (the grouped chain) or None (the full chain, a row a set), N key
+    chunks (the chunked layout) or None (the dense (S, K) layout)."""
 
+    S: int
+    K: int
+    U: int | None = None
+    N: int | None = None
 
-def _chain_keys(S: int, K: int, U: int | None = None, N: int | None = None) -> list[tuple]:
-    """The stage keys of bucket (S, K)'s full chain, or of its grouped
-    chain over U message rows; its keys in the bucket's key_chunks(S, K)
-    chunks, or in N where a batch needs more."""
-    names = _VERIFY_CHAIN if U is None else _GROUPED_CHAIN
-    N = N or key_chunks(S, K)
-    return [_stage_key(name, S, K, U, N) for name in names]
+    @staticmethod
+    def bucket(S: int, K: int) -> list["Chain"]:
+        """The chains bucket (S, K) is built with: its full chain and, where
+        S >= GROUP_MIN_S, its grouped chain over S/2 rows, both with the
+        bucket's key_chunks(S, K)."""
+        N = key_chunks(S, K)
+        return [Chain(S, K, None, N)] + ([Chain(S, K, S // 2, N)] if S >= GROUP_MIN_S else [])
 
+    def __lt__(self, other: "Chain") -> bool:  # a total order: None sorts first
+        return (self.S, self.K, self.U or 0, self.N or 0) < (
+            other.S, other.K, other.U or 0, other.N or 0)
 
-def _bucket_chains(S: int, K: int) -> list[tuple]:
-    """The chains a bucket is built with: (S, K), and (S, K, S/2) where
-    the grouped chain exists."""
-    return [(S, K)] + ([(S, K, S // 2)] if S >= GROUP_MIN_S else [])
+    def stage_keys(self) -> list[tuple]:
+        """The key of each stage program, (name, *shapes): unpack (S, K, U,
+        N), keys (S, K, N), sigs and h2c (S), group (S, U), pairs its rows
+        (U, or S), final none."""
+        S, K, U, N = self.S, self.K, self.U, self.N
+        shapes = {"unpack": (S, K, U, N), "keys": (S, K, N), "sigs": (S,), "h2c": (S,),
+                  "group": (S, U), "pairs": (U or S,), "final": ()}
+        names = _VERIFY_CHAIN if U is None else _GROUPED_CHAIN
+        return [(name, *shapes[name]) for name in names]
+
+    def staged_fields(self) -> list[tuple[str, tuple, bool]]:
+        """(name, shape, is_bool) of each staged array, in the buffer's
+        order: the keys in (S, K) slots, or (N, KEY_CHUNK) with each chunk's
+        set in chunk_set; u one row a set, or U message rows with each
+        set's row in group."""
+        S = self.S
+        slots = (S, self.K) if self.N is None else (self.N, KEY_CHUNK)
+        fields = [
+            ("pk_x", (*slots, 32), False),
+            ("pk_y", (*slots, 32), False),
+            ("pk_inf", slots, True),
+            ("sig_x", (S, 2, 32), False),
+            ("sig_y", (S, 2, 32), False),
+            ("sig_inf", (S,), True),
+            ("u", (self.U or S, 2, 2, 32), False),
+            ("r_bits", (S, 64), False),
+        ]
+        if self.N is not None:
+            fields.append(("chunk_set", (self.N,), False))
+        if self.U is not None:
+            fields.append(("group", (S,), False))
+        return fields
 
 
 def verify_stage(key: tuple):
     """(fn, arg specs) of one stage program of the chain, by stage key."""
     from .curve import Proj
 
-    name, shape = key[0], key[1:]
+    name, *shape = key
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
     b = lambda *s: jax.ShapeDtypeStruct(s, jnp.bool_)  # noqa: E731
+    g1 = lambda S: Proj(*[i32(S, 32)] * 3)  # noqa: E731
+    g2 = lambda S: Proj(*[i32(S, 2, 32)] * 3)  # noqa: E731
     if name == "final":
         return _final_stage, (i32(2, 3, 2, 32), b(), b())
-    S = shape[0]
+    S, *rest = shape  # pairs: its rows
+    if name == "unpack":
+        chain = Chain(S, *rest)
+        n = sum(int(np.prod(sh)) for _, sh, _ in chain.staged_fields())
+
+        def _unpack_stage(flat):  # named: traces and profiles show the jit name
+            return _unpack_staged(flat, chain)
+
+        return _unpack_stage, (i32(n),)
+    if name == "keys":
+        K, N = rest
+        if N is None:
+            return _keys_stage, (i32(S, K, 32), i32(S, K, 32), b(S, K), i32(S, 64))
+        C = KEY_CHUNK
+        keys = partial(_keys_stage, spans=K // C)
+        keys.__name__ = _keys_stage.__name__  # the jit name traces and profiles show
+        return keys, (i32(N, C, 32), i32(N, C, 32), b(N, C), i32(S, 64), i32(N))
     if name == "sigs":
         return _sigs_stage, (i32(S, 2, 32), i32(S, 2, 32), b(S), i32(S, 64))
     if name == "h2c":
         from .h2c import hash_to_g2_device
 
         return hash_to_g2_device, (i32(S, 2, 2, 32),)
-    g1 = Proj(*[i32(S, 32)] * 3)
-    if name == "pairs":
-        g2 = Proj(*[i32(S, 2, 32)] * 3)
-        return _pairs_stage, (g1, g2, Proj(*[i32(2, 32)] * 3))
     if name == "group":
-        U = shape[1]
+        (U,) = rest
 
         def _group_stage(r_pk, group, H):  # named: traces and profiles show the jit name
             # H: the S-row hash of the tiled message rows; its first U are theirs
             return _group_sums(r_pk, group, U), Proj(*(c[:U] for c in H))
 
-        return _group_stage, (g1, i32(S), Proj(*[i32(S, 2, 32)] * 3))
-    K = shape[1]
-    if name == "keys":
-        if len(shape) == 2:
-            return _keys_stage, (i32(S, K, 32), i32(S, K, 32), b(S, K), i32(S, 64))
-        N, C = shape[2], KEY_CHUNK
-        keys = partial(_keys_stage, spans=K // C)
-        keys.__name__ = _keys_stage.__name__  # the jit name traces and profiles show
-        return keys, (i32(N, C, 32), i32(N, C, 32), b(N, C), i32(S, 64), i32(N))
-    U = shape[2] if len(shape) > 2 else None
-    N = shape[3] if len(shape) > 3 else None
-    n = sum(int(np.prod(sh)) for sh, _ in _staged_specs(S, K, U, N))
-
-    def _unpack_stage(flat):  # named: traces and profiles show the jit name
-        return _unpack_staged(flat, S, K, U, N)
-
-    return _unpack_stage, (i32(n),)
+        return _group_stage, (g1(S), i32(S), g2(S))
+    return _pairs_stage, (g1(S), g2(S), Proj(*[i32(2, 32)] * 3))  # pairs
 
 
 def _lower_stage(key: tuple):
@@ -600,7 +593,7 @@ def _lower_stage(key: tuple):
 _kernels: dict[tuple, Future] = {}
 _kernels_lock = threading.Lock()
 _kernel_stats = {"hits": 0, "misses": 0}
-_chains: dict[tuple[int, int], object] = {}
+_chains: dict[Chain, object] = {}
 # concurrent stage compiles: each peaks at a few GB of host memory
 COMPILE_WORKERS = 4
 
@@ -678,13 +671,13 @@ def compile_verify_kernels(buckets) -> dict[tuple[int, int], float]:
     compile or cache load a `bls_compile` span in a pool thread; the
     profiler annotation of each carries the stage key. Each build counts
     its persistent-cache result (lighthouse_tpu_bls_compile_cache_total)."""
-    ready = _compile_chains([chain for S, K in buckets for chain in _bucket_chains(S, K)])
-    return {(S, K): max(ready[chain] for chain in _bucket_chains(S, K)) for S, K in buckets}
+    ready = _compile_chains([chain for S, K in buckets for chain in Chain.bucket(S, K)])
+    return {(S, K): max(ready[chain] for chain in Chain.bucket(S, K)) for S, K in buckets}
 
 
-def _compile_chains(chains) -> dict[tuple, float]:
-    """compile_verify_kernels' builder, by chain (_chain_keys' arguments):
-    the seconds until each chain's last stage was ready."""
+def _compile_chains(chains) -> dict[Chain, float]:
+    """Build the stage programs of the given chains (compile_verify_kernels'
+    work): the seconds until each chain's last stage was ready."""
     from ....common.tracing import span
 
     t0 = time.perf_counter()
@@ -694,10 +687,10 @@ def _compile_chains(chains) -> dict[tuple, float]:
     def note(key):
         return lambda _fut: done_at.setdefault(key, time.perf_counter() - t0)
 
-    chains = sorted(chains, key=len)  # stable: the full chains (S, K) first
+    chains = sorted(chains, key=lambda chain: chain.U is not None)  # stable: full chains first
     with ThreadPoolExecutor(max_workers=COMPILE_WORKERS) as pool:
         for chain in chains:
-            for key in _chain_keys(*chain):
+            for key in chain.stage_keys():
                 if key in futures:
                     continue
                 fut, owner = _claim_kernel(key)
@@ -713,7 +706,7 @@ def _compile_chains(chains) -> dict[tuple, float]:
                 fut.add_done_callback(note(key))
     ready = {}
     for chain in chains:
-        keys = _chain_keys(*chain)
+        keys = chain.stage_keys()
         for key in keys:
             futures[key].result()
         ready[chain] = max(done_at[key] for key in keys)
@@ -727,34 +720,31 @@ def compiled_stage(key: tuple):
     return _kernels[key].result()
 
 
-def _verify_kernel(S: int, K: int, U: int | None = None, N: int | None = None):
-    """The batch-verify chain for bucket (S, K), or its grouped chain over
-    U message rows, its keys in N chunks where a batch needs more than the
-    bucket's key_chunks(S, K): flat staged buffer (see _pack_staged) ->
+def _verify_kernel(chain: Chain):
+    """The batch-verify chain: flat staged buffer (see _pack_staged) ->
     device bool, dispatched stage by stage without a host sync. A bucket's
-    own chains are built as compile_verify_kernels builds them; a chain of
-    more chunks alone, on first use."""
-    chain = (S, K, U, N) if N is not None else (S, K) if U is None else (S, K, U)
+    own chains are built as compile_verify_kernels builds them; any other
+    chain (over budget) alone, on first use."""
     run = _chains.get(chain)
     if run is not None:
         return run
-    if N is None:
-        compile_verify_kernels([(S, K)])
+    if chain in Chain.bucket(chain.S, chain.K):
+        compile_verify_kernels([(chain.S, chain.K)])
     else:
         _compile_chains([chain])
-    stages = [_kernels[key].result() for key in _chain_keys(*chain)]
-    unpack, keys, sigs, h2c, *group, pairs, final = stages
-    chunked = key_chunks(S, K) is not None  # chunk_set follows r_bits
+    prog = {key[0]: _kernels[key].result() for key in chain.stage_keys()}
+    fields = [name for name, _, _ in chain.staged_fields()]
+    chunked = chain.N is not None
 
     def run(flat):
-        staged = unpack(flat)
-        r_bits = staged[7]
-        r_pk, agg_inf = keys(*staged[:3], r_bits, *staged[8 : 8 + chunked])
-        sig_acc, sub_ok = sigs(*staged[3:6], r_bits)
-        rows = (r_pk, h2c(staged[6]))
-        if group:
-            rows = group[0](r_pk, staged[-1], rows[1])
-        return final(pairs(*rows, sig_acc), sub_ok, agg_inf)
+        a = dict(zip(fields, prog["unpack"](flat)))
+        r_pk, agg_inf = prog["keys"](a["pk_x"], a["pk_y"], a["pk_inf"], a["r_bits"],
+                                     *([a["chunk_set"]] if chunked else []))
+        sig_acc, sub_ok = prog["sigs"](a["sig_x"], a["sig_y"], a["sig_inf"], a["r_bits"])
+        H = prog["h2c"](a["u"])
+        if "group" in prog:
+            r_pk, H = prog["group"](r_pk, a["group"], H)
+        return prog["final"](prog["pairs"](r_pk, H, sig_acc), sub_ok, agg_inf)
 
     _chains[chain] = run
     return run
@@ -840,24 +830,38 @@ def stage_sets(sets: list[SignatureSet], rng=None, s_floor: int = 4):
     key layout and message rows are counted, real and padding
     (lighthouse_tpu_bls_staged_rows_total, ..._staged_key_slots_total,
     ..._keys_layout_total, ..._message_rows_total)."""
-    return _stage(sets, rng, s_floor, dispatch=False)[0]
+    return tuple(_stage(sets, rng, s_floor, lambda sets, S, K: (Chain(S, K), None))[0].values())
 
 
 def stage_sets_grouped(sets: list[SignatureSet], rng=None, s_floor: int = 4):
-    """The dispatch's staging: stage_sets, with the message plan
-    (_message_plan) made inside the same `bls_stage` span, and the keys in
-    the chunked layout where K > KEY_CHUNK (key_chunks). Returns (staged,
-    group): staged is stage_sets' arrays in the plan's row order, its keys
+    """The dispatch's staging: stage_sets, for the chain _dispatch_chain
+    chooses inside the same `bls_stage` span. Returns (staged, group):
+    staged is stage_sets' arrays in the plan's row order, its keys
     (N, KEY_CHUNK) slots followed by the (N,) chunk_set after r_bits where
     chunked, u the U message rows where grouped; group the (S,) int32 group
     index of each row, or None where the batch takes the full chain."""
-    return _stage(sets, rng, s_floor, dispatch=True)[:2]
+    staged, _ = _stage(sets, rng, s_floor, _dispatch_chain)
+    group = staged.pop("group", None)
+    return tuple(staged.values()), group
 
 
-def _stage(sets, rng, s_floor: int, dispatch: bool):
-    """stage_sets (dense, no message plan) or the dispatch's staging
-    (stage_sets_grouped). Returns (staged, group, chain): chain the
-    arguments of the batch's _verify_kernel."""
+def _dispatch_chain(sets, S: int, K: int):
+    """The chain a batch in bucket (S, K) dispatches to, and its message
+    plan: grouped where _message_plan groups the sets; the keys in the
+    bucket's key_chunks(S, K) chunks where K > KEY_CHUNK, or in the next
+    power of two of the chunks they fill where that is more (over budget)."""
+    plan = _message_plan([s.message for s in sets], S)
+    N = key_chunks(S, K)
+    if N is not None:  # a set fills ceil(k / KEY_CHUNK) chunks, a padding row one
+        used = S - len(sets) + sum(-(-len(s.signing_keys) // KEY_CHUNK) for s in sets)
+        N = max(N, _next_pow2(used))
+    return Chain(S, K, None if plan is None else S // 2, N), plan
+
+
+def _stage(sets, rng, s_floor: int, choose):
+    """Stage a batch for the chain, and message plan or None, that
+    choose(sets, S, K) gives in its bucket. Returns (staged, chain): staged
+    the arrays by name, in the chain's buffer order."""
     from ....common.metrics import (
         BLS_BATCH_PADDED_SIZE,
         BLS_KEYS_LAYOUT_TOTAL,
@@ -878,10 +882,10 @@ def _stage(sets, rng, s_floor: int, dispatch: bool):
     BLS_STAGED_ROWS_TOTAL.labels(row="pad").inc(S - n)
 
     with BLS_STAGE_SECONDS.time(), span("bls_stage"):
-        grouping = _message_plan(msgs, S) if dispatch else None
+        chain, plan = choose(sets, S, K)
         # the plan orders the real rows (its padding rows stay last), so a
         # chunked set's chunks follow the grouped row order
-        order = None if grouping is None else grouping[0][:n]
+        order = None if plan is None else plan[0][:n]
         rows = sets if order is None else [sets[i] for i in order]
         # Only the real keys and the padding rows' generators are packed;
         # the other key slots are infinity (zero limbs, flag set) and are
@@ -899,15 +903,12 @@ def _stage(sets, rng, s_floor: int, dispatch: bool):
         # Where each row's keys start: row i at slot i*K (dense), or at its
         # first chunk's first slot (chunked: ceil(k / KEY_CHUNK) chunks a
         # row, one for a padding row, the unused chunks last, set index S).
-        N = key_chunks(S, K) if dispatch else None
-        layout = "dense" if N is None else "chunked"
+        N, chunk_set = chain.N, None
         if N is None:
             slots, row_slot = (S, K), np.arange(S) * K
         else:
             n_chunk = -(-n_row // KEY_CHUNK)
             used = int(n_chunk.sum())
-            if used > N:
-                N, layout = _next_pow2(used), "chunked_over_budget"
             slots = (N, KEY_CHUNK)
             row_slot = (np.cumsum(n_chunk) - n_chunk) * KEY_CHUNK
             chunk_set = np.full(N, S, dtype=np.int32)
@@ -946,28 +947,26 @@ def _stage(sets, rng, s_floor: int, dispatch: bool):
             sig_x, sig_y, sig_inf = pack_g2_batch(sig_pts)
         with span("bls_h2c_host"):
             u = h2c.hash_to_field_limbs(
-                msgs + [b""] * (S - n) if grouping is None else grouping[2])
-    staged = (pk_x, pk_y, pk_inf, sig_x, sig_y, sig_inf, u, r_rows)
-    if layout != "dense":
-        staged += (chunk_set,)
+                msgs + [b""] * (S - n) if plan is None else plan[2])
+    arrays = {"pk_x": pk_x, "pk_y": pk_y, "pk_inf": pk_inf, "sig_x": sig_x, "sig_y": sig_y,
+              "sig_inf": sig_inf, "u": u, "r_bits": r_rows, "chunk_set": chunk_set,
+              "group": None if plan is None else plan[1]}
     n_keys = len(key_pts) - (S - n)
+    layout = "dense" if N is None else "chunked" if N == key_chunks(S, K) else "chunked_over_budget"
     BLS_KEYS_LAYOUT_TOTAL.labels(layout=layout).inc()
     BLS_STAGED_KEY_SLOTS_TOTAL.labels(slot="key").inc(n_keys)
     BLS_STAGED_KEY_SLOTS_TOTAL.labels(slot="pad").inc(n_slots - n_keys)
     distinct = len(set(msgs))
     BLS_MESSAGE_ROWS_TOTAL.labels(row="distinct").inc(distinct)
     BLS_MESSAGE_ROWS_TOTAL.labels(row="pad").inc(len(u) - distinct)
-    group = None if grouping is None else grouping[1]
-    U = None if grouping is None else len(u)
-    chain = (S, K, U, N) if layout == "chunked_over_budget" else (S, K) if U is None else (S, K, U)
-    return staged, group, chain
+    return {name: arrays[name] for name, _, _ in chain.staged_fields()}, chain
 
 
 def drop_staging_caches(sets) -> None:
-    """Bench/profiling/test helper: forget every staging cache a batch could
-    hit — the process-wide h2c LRU and the per-point limb rows of all
-    referenced points — so the next stage_sets runs fully cold. Keeping the
-    invalidation next to the caches stops the warm-vs-cold tools from
+    """Test helper: forget every staging cache a batch could hit — the
+    process-wide h2c LRU and the per-point limb rows of all referenced
+    points — so the next stage_sets runs fully cold. Keeping the
+    invalidation next to the caches stops a warm-vs-cold test from
     silently measuring a half-warm baseline when a cache is added."""
     from . import h2c
 
@@ -1043,14 +1042,12 @@ def verify_signature_sets_async(
 
     if not _structurally_valid(sets):
         return _INVALID
-    staged, group, chain = _stage(sets, rng, s_floor, dispatch=True)
-    kernel = _verify_kernel(*chain)
-    if group is not None:
-        staged = (*staged, group)
+    staged, chain = _stage(sets, rng, s_floor, _dispatch_chain)
+    kernel = _verify_kernel(chain)
     # the host side of a dispatch's fixed cost: one buffer packed, copied
     # to the device, and the chain's programs launched
     with span("bls_dispatch"):
-        return VerifyFuture(kernel(jnp.asarray(_pack_staged(staged))))
+        return VerifyFuture(kernel(jnp.asarray(_pack_staged(staged.values()))))
 
 
 def verify_signature_sets(sets: list[SignatureSet], rng=None) -> bool:

@@ -2,7 +2,7 @@
 (and the jax backend when LIGHTHOUSE_TPU_CONFORMANCE_JAX=1 — kept off the
 default CI path because every kernel shape is a multi-minute cold XLA
 compile on the 1-core CPU mesh; the shapes are exercised on the real chip
-by chip_smoke.py and bench.py).
+by chip_smoke.py and the benchmark, perfbench/).
 
 The fake backend is deliberately excluded, as in the reference: its
 verifications are unconditionally true (/root/reference/Makefile:102 runs

@@ -117,14 +117,15 @@ def test_staging_counts_message_rows(keys, n_sets, distinct, want):
     assert {r: BLS_MESSAGE_ROWS_TOTAL.labels(row=r).value - before[r] for r in want} == want
 
 
-@pytest.mark.parametrize("distinct,chain", [(64, (128, 4, 64)), (65, (128, 4))])
+@pytest.mark.parametrize("distinct,chain", [(64, api.Chain(128, 4, 64)), (65, api.Chain(128, 4))])
 def test_the_dispatch_takes_the_chain_the_plan_chose(keys, monkeypatch, distinct, chain):
     """The grouped chain gets the U-row layout with the group index last."""
     got = []
-    monkeypatch.setattr(api, "_verify_kernel", lambda *c: got.append(c) or (lambda flat: flat))
+    monkeypatch.setattr(api, "_verify_kernel", lambda c: got.append(c) or (lambda flat: flat))
     fut = api.verify_signature_sets_async(_sets(keys, _msgs(128, distinct)))
     assert got == [chain]
-    n = sum(int(np.prod(shape)) for shape, _ in api._staged_specs(*chain))
+    assert chain.staged_fields()[-1][0] == ("group" if chain.U else "r_bits")
+    n = sum(int(np.prod(shape)) for _, shape, _ in chain.staged_fields())
     assert np.asarray(fut._result).shape == (n,)
 
 
@@ -148,7 +149,7 @@ def test_group_stage_sums_each_group_and_leaves_empty_groups_at_infinity():
     group = np.array([0, 0, 0, 1, 1, 2, 2, 2], np.int32)  # group 3 has no set
     x, y, inf = pack.pack_g1_batch(pts)
     H = Proj(*(np.arange(S * 2 * 32, dtype=np.int32).reshape(S, 2, 32) + c for c in (0, 1, 2)))
-    fn, _ = api.verify_stage(api._stage_key("group", S, 1, U))
+    fn, _ = api.verify_stage(("group", S, U))
     out, HU = jax.jit(fn)(from_affine(FP, jnp.asarray(x), jnp.asarray(y), jnp.asarray(inf)),
                           jnp.asarray(group), Proj(*map(jnp.asarray, H)))
     assert all(np.array_equal(np.asarray(a), b[:U]) for a, b in zip(HU, H))
@@ -210,7 +211,7 @@ def _swap(sets, a, b):
 
 
 @pytest.mark.slow
-@pytest.mark.parametrize("n_msgs,chain", [(64, (128, 4, 64)), (65, (128, 4))],
+@pytest.mark.parametrize("n_msgs,chain", [(64, api.Chain(128, 4, 64)), (65, api.Chain(128, 4))],
                          ids=["grouped", "full-width"])
 def test_gossip_batch_verifies_true_with_ref_parity(gossip, n_msgs, chain):
     sets = gossip(n_msgs)

@@ -171,7 +171,7 @@ def test_the_unpack_program_has_a_stable_name():
 
     from lighthouse_tpu.crypto.bls.jax_backend import api
 
-    fn, specs = api.verify_stage(api._stage_key("unpack", 4, 1))
+    fn, specs = api.verify_stage(api.Chain(4, 1).stage_keys()[0])
     assert "_unpack_stage" in jax.jit(fn).lower(*specs).as_text().split("\n", 1)[0]
 
 
@@ -212,7 +212,7 @@ def test_bisection_halves_are_staged_counted_and_spanned(monkeypatch, fresh_trac
     from lighthouse_tpu.crypto.bls.batch_verifier import BatchVerifier, _Ready
     from lighthouse_tpu.crypto.bls.jax_backend import api
 
-    monkeypatch.setattr(api, "_verify_kernel", lambda S, K: (lambda flat: True))
+    monkeypatch.setattr(api, "_verify_kernel", lambda chain: (lambda flat: True))
     sets = _sets([1, 1, 1, 1])
     bad = sets[2]
 
@@ -252,7 +252,7 @@ def test_a_direct_batch_times_the_verdict_copy_apart_from_the_device_wait(monkey
             calls.append("copy")
             return True
 
-    monkeypatch.setattr(api, "_verify_kernel", lambda S, K: (lambda flat: Verdict()))
+    monkeypatch.setattr(api, "_verify_kernel", lambda chain: (lambda flat: Verdict()))
     assert api.verify_signature_sets(_sets([1, 2])) is True
     assert calls == ["ready", "copy"]
     counts = _counts(fresh_tracer)

@@ -46,7 +46,7 @@ def keys():
 def keys_stage():
     """The keys stage at (8, 128): dense, and chunked in the bucket's 16
     chunks, compiled concurrently."""
-    stage_keys = [("keys", S, K), ("keys", S, K, N)]
+    stage_keys = [("keys", S, K, None), ("keys", S, K, N)]
     lowered = [jax.jit(fn).lower(*specs) for fn, specs in map(api.verify_stage, stage_keys)]
     with ThreadPoolExecutor(len(lowered)) as pool:
         compiled = list(pool.map(lambda low: low.compile(), lowered))
@@ -238,12 +238,12 @@ def test_an_over_budget_batch_dispatches_a_chain_of_its_own(keys, monkeypatch):
     """The dispatch names the chunk count only where a batch passes the
     bucket's, and sends a buffer of the layout that chain unpacks."""
     got = []
-    monkeypatch.setattr(api, "_verify_kernel", lambda *c: got.append(c) or (lambda flat: flat))
+    monkeypatch.setattr(api, "_verify_kernel", lambda c: got.append(c) or (lambda flat: flat))
     for lists in ([1, 33, 128], [128, 128, 128, 128, 1]):
         fut = api.verify_signature_sets_async(_sets(keys, [keys[:k] for k in lists]))
-        n = sum(int(np.prod(shape)) for shape, _ in api._staged_specs(*got[-1]))
+        n = sum(int(np.prod(shape)) for _, shape, _ in got[-1].staged_fields())
         assert np.asarray(fut._result).shape == (n,)
-    assert got == [(4, 128), (8, 128, None, 2 * N)]
+    assert got == [api.Chain(4, 128, None, api.key_chunks(4, 128)), api.Chain(8, 128, None, 2 * N)]
 
 
 class _Lowered:
@@ -261,7 +261,7 @@ def test_an_over_budget_chain_builds_only_its_unpack_and_keys(monkeypatch):
     api.compile_verify_kernels([(S, K)])
     built = len(lowered)
     assert ("keys", S, K, N) in lowered and ("unpack", S, K, None, N) in lowered
-    api._verify_kernel(S, K, None, 2 * N)
+    api._verify_kernel(api.Chain(S, K, None, 2 * N))
     assert lowered[built:] == [("unpack", S, K, None, 2 * N), ("keys", S, K, 2 * N)]
 
 

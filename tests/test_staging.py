@@ -339,23 +339,23 @@ def test_verify_stages_build_once_across_threads_and_buckets(monkeypatch):
 
     def lower(key):
         lowered.append(key)
-        return _FakeLowered(gate, builds, fail=key == ("keys", 8, 2))
+        return _FakeLowered(gate, builds, fail=key == ("keys", 8, 2, None))
 
     monkeypatch.setattr(japi, "_lower_stage", lower)
     monkeypatch.setattr(japi, "_kernels", {})
     monkeypatch.setattr(japi, "_chains", {})
     got = []
-    waiter = threading.Thread(target=lambda: got.append(japi._verify_kernel(4, 1)))
+    waiter = threading.Thread(target=lambda: got.append(japi._verify_kernel(japi.Chain(4, 1))))
     waiter.start()
     deadline = time.monotonic() + 10.0
     while not lowered and time.monotonic() < deadline:
         time.sleep(0.001)
-    again = threading.Thread(target=lambda: got.append(japi._verify_kernel(4, 1)))
+    again = threading.Thread(target=lambda: got.append(japi._verify_kernel(japi.Chain(4, 1))))
     again.start()
     gate.set()
     waiter.join(10.0)
     again.join(10.0)
-    assert len(got) == 2 and got[1] is japi._verify_kernel(4, 1)
+    assert len(got) == 2 and got[1] is japi._verify_kernel(japi.Chain(4, 1))
     assert len(builds) == len(japi._VERIFY_CHAIN)
 
     # (4, 64) shares the S-only and shape-free stages; (16, 4) final only,
@@ -363,10 +363,56 @@ def test_verify_stages_build_once_across_threads_and_buckets(monkeypatch):
     ready = japi.compile_verify_kernels([(4, 64), (16, 4)])
     assert set(ready) == {(4, 64), (16, 4)}
     assert len(builds) == len(japi._VERIFY_CHAIN) + 2 + 5 + 3
-    assert lowered.index(("pairs", 16)) < lowered.index(("unpack", 16, 4, 8))
+    assert lowered.index(("pairs", 16)) < lowered.index(("unpack", 16, 4, 8, None))
     with pytest.raises(RuntimeError, match="compile refused"):
         japi.compile_verify_kernels([(8, 2)])
-    assert ("keys", 8, 2) not in japi._kernels
+    assert ("keys", 8, 2, None) not in japi._kernels
+
+
+def _chain_stages(S, K, U, N, rows):
+    """A chain's stage keys: the shared ones, and between h2c and pairs the
+    grouped chain's group stage."""
+    group = [("group", S, U)] if U else []
+    return [("unpack", S, K, U, N), ("keys", S, K, N), ("sigs", S), ("h2c", S), *group,
+            ("pairs", rows), ("final",)]
+
+
+# (chain, its stage keys, its place among all of them sorted)
+CHAINS = {
+    "16x64-full": (japi.Chain(16, 64, None, 32), _chain_stages(16, 64, None, 32, 16), 0),
+    "16x64-over-budget": (japi.Chain(16, 64, None, 64), _chain_stages(16, 64, None, 64, 16), 1),
+    "16x64-grouped": (japi.Chain(16, 64, 8, 32), _chain_stages(16, 64, 8, 32, 8), 2),
+    "128x4-full": (japi.Chain(128, 4), _chain_stages(128, 4, None, None, 128), 3),
+    "128x4-grouped": (japi.Chain(128, 4, 64), _chain_stages(128, 4, 64, None, 64), 4),
+    "256x512-full": (japi.Chain(256, 512, None, 1024),
+                     _chain_stages(256, 512, None, 1024, 256), 5),
+    "256x512-over-budget": (japi.Chain(256, 512, None, 2048),
+                            _chain_stages(256, 512, None, 2048, 256), 6),
+    "256x512-grouped": (japi.Chain(256, 512, 128, 1024),
+                        _chain_stages(256, 512, 128, 1024, 128), 7),
+}
+
+
+@pytest.mark.parametrize("name", CHAINS)
+def test_a_chain_plans_its_stage_keys_and_staged_layout(monkeypatch, name):
+    """Each chain's stage keys; its staged fields fill the unpack program's
+    input exactly and are its outputs (u at S rows: the grouped chain tiles
+    its U message rows); a bucket is built with its full and grouped chains
+    only; and the chains dispatched sort (perfbench logs them sorted)."""
+    import jax
+
+    chain, keys, rank = CHAINS[name]
+    assert chain.stage_keys() == keys
+    assert (chain in japi.Chain.bucket(chain.S, chain.K)) == ("over-budget" not in name)
+    fields = chain.staged_fields()
+    fn, (flat,) = japi.verify_stage(keys[0])
+    assert flat.shape == (sum(int(np.prod(shape)) for _, shape, _ in fields),)
+    out = jax.eval_shape(fn, flat)
+    assert [(a.shape, a.dtype == bool) for a in out] == [
+        ((chain.S, *shape[1:]) if field == "u" else shape, is_bool)
+        for field, shape, is_bool in fields]
+    monkeypatch.setattr(japi, "_chains", {c: None for c, _, _ in CHAINS.values()})
+    assert sorted(japi._chains).index(chain) == rank
 
 
 def test_ragged_batch_packs_only_real_keys_as_the_slow_path(jax_bls, monkeypatch):
@@ -410,7 +456,7 @@ def test_dispatch_buffer_is_the_staged_arrays_as_int32_in_order(sets):
     flat = japi._pack_staged(staged)
     want = np.concatenate([np.ravel(np.asarray(a)).astype(np.int32) for a in staged])
     assert flat.dtype == np.int32 and np.array_equal(flat, want)
-    assert flat.size == sum(int(np.prod(s)) for s, _ in japi._staged_specs(16, 4))
+    assert flat.size == sum(int(np.prod(s)) for _, s, _ in japi.Chain(16, 4).staged_fields())
 
 
 def test_staging_floor_rounds_to_a_pow2_bucket(sets):

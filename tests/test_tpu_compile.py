@@ -104,11 +104,11 @@ def test_group_stage_compiles_for_one_v5e_chip(one_chip):
 @pytest.mark.slow
 @pytest.mark.parametrize(
     "chain",
-    [(S, K), api.batch_bucket(128, 1), (*api.batch_bucket(128, 1), 64)],
+    [*api.Chain.bucket(S, K)[:1], *api.Chain.bucket(*api.batch_bucket(128, 1))],
     ids=["block_256x128", "gossip_128x4", "gossip_128x4_grouped_64"],
 )
 def test_verify_stages_compile_for_one_v5e_chip(one_chip, chain):
     """Every stage program of a bucket's verify chain (api._VERIFY_CHAIN),
     or of its grouped chain (api._GROUPED_CHAIN: h2c and pairs at U rows)."""
-    for key in api._chain_keys(*chain):
+    for key in chain.stage_keys():
         assert _device_bytes(_compile_stage(one_chip, key)) < HBM_BYTES, key
